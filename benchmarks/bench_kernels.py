@@ -45,8 +45,8 @@ def run():
 
     p_tot, page, maxp = 64, 16, 16
     q2 = jax.random.normal(ks[0], (8, h, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (p_tot, page, kv, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (p_tot, page, kv, d), jnp.float32)
+    kp = jax.random.normal(ks[1], (p_tot, kv, page, d), jnp.float32)
+    vp = jax.random.normal(ks[2], (p_tot, kv, page, d), jnp.float32)
     bt = jax.random.randint(key, (8, maxp), 0, p_tot)
     cl = (jnp.arange(8) * 29 % (maxp * page - 1) + 1).astype(jnp.int32)
     fn2 = jax.jit(ref.paged_decode_attention_ref)

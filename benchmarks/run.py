@@ -17,6 +17,9 @@ def main() -> None:
                     help="comma-separated bench names")
     args, _ = ap.parse_known_args()
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
+
     from benchmarks import (bench_balancer_ablation, bench_cluster_scaling,
                             bench_fig3_predictor_fit, bench_fig4_latency,
                             bench_kernels, bench_offload_limitation,

@@ -149,8 +149,11 @@ class RealExecutor:
     # ------------------------------------------------------------------
     def _run(self, inputs, positions, decode: bool, active_mask=None,
              enc_out=None):
-        kvp = jnp.asarray(self.pos)
-        cl = jnp.asarray(self.lens)
+        # copies: on CPU jnp.asarray may alias an aligned numpy buffer, and
+        # the host rewrites pos/lens in place while this asynchronously
+        # dispatched step can still be reading them
+        kvp = jnp.asarray(self.pos.copy())
+        cl = jnp.asarray(self.lens.copy())
         if self._enc_dec:
             logits, new_cache, _ = self.model.forward(
                 self.params, jnp.asarray(inputs), self.cache, cl,
@@ -297,9 +300,10 @@ class PagedRealExecutor:
     """JAX execution over a block-pool KV cache driven by the engine's live
     block tables.
 
-    Layout: per layer, K and V pools of shape ``[num_blocks + 1, block_size,
-    n_kv_heads, head_dim]`` (stacked to ``[L, P+1, bs, Kv, D]`` for the layer
-    scan). Pool row ``i`` *is* allocator block ``i`` — the engine's
+    Layout: per layer, K and V pools of shape ``[num_blocks + 1,
+    n_kv_heads, block_size, head_dim]`` (stacked to ``[L, P+1, Kv, bs, D]``
+    for the layer scan) — head-major, the paged decode kernel's layout.
+    Pool row ``i`` *is* allocator block ``i`` — the engine's
     :class:`~repro.kvcache.BlockAllocator` decides placement and this
     executor just reads/writes through the tables, so:
 
@@ -320,8 +324,9 @@ class PagedRealExecutor:
     Decode runs :func:`repro.kernels.ops.paged_decode_attention` over the
     pool + gathered block tables; prefill chunks run
     :func:`repro.kernels.ops.chunked_prefill_attention` over the request's
-    gathered pages. ``use_pallas=None`` auto-selects the Pallas TPU kernels
-    on TPU backends and the jnp reference path elsewhere (CPU CI).
+    gathered pages. ``use_pallas=None`` auto-selects the compiled Pallas
+    kernels on TPU backends and the jnp reference path elsewhere (CPU CI).
+    The pools are made on the device that holds ``params``.
 
     Supported model families: dense-attention stacks ("mlp" kind, e.g. the
     llama3 smoke arch) without sliding windows. MoE/SSM/hybrid/MLA/enc-dec
@@ -335,6 +340,27 @@ class PagedRealExecutor:
 
     def __init__(self, model, params, *, use_pallas: Optional[bool] = None,
                  greedy: bool = True):
+        self.check_model(model)
+        self.model = model
+        self.params = params
+        self.cfg = model.cfg
+        if use_pallas is None:
+            use_pallas = jax.default_backend() == "tpu"
+        self.use_pallas = bool(use_pallas)
+        self.greedy = greedy
+        self.buckets = BucketCache()
+        self._engine = None
+        self._allocator = None
+        self.page: Optional[int] = None
+        self.k_pool = None              # [L, P+1, Kv, page, D]
+        self.v_pool = None
+        self._trash: Optional[int] = None
+        self._host_store: Dict[bytes, tuple] = {}   # chain hash -> (K, V)
+
+    @staticmethod
+    def check_model(model) -> None:
+        """Raise NotImplementedError unless the paged path can run
+        ``model`` (a dense-attention stack without sliding windows)."""
         cfg = model.cfg
         kind = model._stack_kind()
         if kind != "mlp" or model.is_mla or model.n_dense:
@@ -349,21 +375,6 @@ class PagedRealExecutor:
             raise NotImplementedError(
                 "PagedRealExecutor does not support sliding-window layers "
                 "(paged decode attends the whole table); use executor='real'")
-        self.model = model
-        self.params = params
-        self.cfg = cfg
-        if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
-        self.use_pallas = bool(use_pallas)
-        self.greedy = greedy
-        self.buckets = BucketCache()
-        self._engine = None
-        self._allocator = None
-        self.page: Optional[int] = None
-        self.k_pool = None              # [L, P+1, page, Kv, D]
-        self.v_pool = None
-        self._trash: Optional[int] = None
-        self._host_store: Dict[bytes, tuple] = {}   # chain hash -> (K, V)
 
     def compile_stats(self) -> Dict[str, int]:
         return self.buckets.compile_stats()
@@ -386,10 +397,12 @@ class PagedRealExecutor:
         cfg = self.cfg
         self.page = ecfg.block_size
         self._trash = ecfg.num_kv_blocks
-        shape = (self.model.n_stack, ecfg.num_kv_blocks + 1, self.page,
-                 cfg.n_kv_heads, cfg.head_dim)
-        self.k_pool = jnp.zeros(shape, self.model.dtype)
-        self.v_pool = jnp.zeros(shape, self.model.dtype)
+        shape = (self.model.n_stack, ecfg.num_kv_blocks + 1, cfg.n_kv_heads,
+                 self.page, cfg.head_dim)
+        # the pools live beside the weights, on the params' device
+        (dev,) = jax.tree.leaves(self.params)[0].devices()
+        self.k_pool = jnp.zeros(shape, self.model.dtype, device=dev)
+        self.v_pool = jnp.zeros(shape, self.model.dtype, device=dev)
         self._engine = engine
         self._hook_allocator(engine.allocator)
         self._build_fns()
@@ -444,7 +457,7 @@ class PagedRealExecutor:
 
     def _clone_block(self, dst: int, src: int, n_tokens: int) -> None:
         """Allocator CoW hook: physically copy the first ``n_tokens`` rows
-        of block ``src`` into ``dst`` (one [L, n, Kv, D] copy — the rest of
+        of block ``src`` into ``dst`` (one [L, Kv, n, D] copy — the rest of
         ``dst`` is garbage until prefill/decode writes it)."""
         self.buckets.record("cow", 1)
         self.k_pool, self.v_pool = self._cow_fn(
@@ -480,12 +493,15 @@ class PagedRealExecutor:
             return q, k, v
 
         def write(pool, rows, write_idx):
-            """Scatter token K/V rows into the flat pool view.
-            pool [P+1, page, Kv, D]; rows [n, Kv, D]; write_idx [n] flat
+            """Scatter token K/V rows into their pages.
+            pool [P+1, Kv, page, D]; rows [n, Kv, D]; write_idx [n] flat
             slots (block_id * page + offset; trash for padded lanes)."""
-            flat = pool.reshape((-1,) + pool.shape[2:])
-            flat = flat.at[write_idx].set(rows.astype(flat.dtype))
-            return flat.reshape(pool.shape)
+            blk, off = write_idx // page, write_idx % page
+            return pool.at[blk, :, off].set(rows.astype(pool.dtype))
+
+        def gather(pool, table):
+            """A table's pages in token order: [1, s, Kv, D]."""
+            return pool[table].transpose(0, 2, 1, 3).reshape(1, -1, kvh, hd)
 
         def finish(x, params):
             x = rmsnorm(x, params["final_norm"], eps)
@@ -514,10 +530,9 @@ class PagedRealExecutor:
                 q, k, v = qkv(lp, hx, positions)
                 kp = write(kp, k[0], write_idx)
                 vp = write(vp, v[0], write_idx)
-                kg = kp[table].reshape(1, s, kvh, hd)
-                vg = vp[table].reshape(1, s, kvh, hd)
                 out = ops.chunked_prefill_attention(
-                    q, kg, vg, positions, kv_pos, window=0,
+                    q, gather(kp, table), gather(vp, table), positions,
+                    kv_pos, window=0,
                     use_pallas=use_pallas)
                 xc = xc + (out.reshape(out.shape[:2] + (h * hd,))
                            @ lp["attn"]["wo"].astype(xc.dtype))
@@ -555,7 +570,7 @@ class PagedRealExecutor:
             keep = jnp.arange(page) < n
 
             def clone(pool):
-                sel = keep.reshape(1, page, 1, 1)
+                sel = keep.reshape(1, 1, page, 1)
                 merged = jnp.where(sel, pool[:, src], pool[:, dst])
                 return pool.at[:, dst].set(merged)
 
@@ -564,10 +579,12 @@ class PagedRealExecutor:
         def inject_fwd(k_pool, v_pool, k_rows, v_rows, dst_idx):
             """k/v_rows [L, n, Kv, D] payload tokens; dst_idx [n] flat
             pool slots (trash-padded)."""
+            blk, off = dst_idx // page, dst_idx % page
+
             def put(pool, rows):
-                flat = pool.reshape((pool.shape[0], -1) + pool.shape[3:])
-                flat = flat.at[:, dst_idx].set(rows.astype(flat.dtype))
-                return flat.reshape(pool.shape)
+                # advanced indices split by a slice lead the result: [n, L, Kv, D]
+                return pool.at[:, blk, :, off].set(
+                    rows.transpose(1, 0, 2, 3).astype(pool.dtype))
 
             return put(k_pool, k_rows), put(v_pool, v_rows)
 
@@ -654,8 +671,10 @@ class PagedRealExecutor:
         table = self._alloc().block_table(self._req_id(slot))
         nblk = math.ceil(upto / self.page)
         idx = jnp.asarray(table[:nblk], jnp.int32)
-        return {"k_pages": self.k_pool[:, idx],
-                "v_pages": self.v_pool[:, idx],
+        # token-major pages [L, nblk, page, Kv, D]: the payload layout does
+        # not depend on how the pool orders heads and tokens
+        return {"k_pages": self.k_pool[:, idx].transpose(0, 1, 3, 2, 4),
+                "v_pages": self.v_pool[:, idx].transpose(0, 1, 3, 2, 4),
                 "_upto": upto, "_page": self.page}
 
     def inject_kv(self, slot: int, payload, upto: int):
@@ -676,8 +695,7 @@ class PagedRealExecutor:
         if n <= 0:
             return
         nb = self.buckets.bucket(n, lo=self.page)
-        l_dim = self.k_pool.shape[0]
-        kvh, hd = self.k_pool.shape[3], self.k_pool.shape[4]
+        l_dim, _, kvh, _, hd = self.k_pool.shape
         k_rows = np.zeros((l_dim, nb, kvh, hd), np.asarray(
             payload["k_pages"]).dtype)
         v_rows = np.zeros_like(k_rows)

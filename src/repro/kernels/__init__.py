@@ -13,14 +13,15 @@ use_pallas=False)``
     marking padding; a kv token attends iff ``0 <= kv_pos <= q_pos``
     (causal), windowed variants additionally require ``q_pos - kv_pos <
     window``. ``use_pallas=False`` dispatches the pure-jnp reference
-    (CPU/CI); ``True`` the Pallas TPU kernel (``interpret=True`` runs it
-    on CPU).
+    (CPU/CI); ``True`` the compiled Pallas TPU kernel (``interpret=True``
+    runs it in the Pallas interpreter, for CPU tests only).
 
 ``ops.paged_decode_attention(q, k_pages, v_pages, block_tables,
 context_lens, *, use_pallas=False)``
     One decode step over block-pooled KV. ``q`` is ``[B, H, D]``,
-    ``k_pages``/``v_pages`` are the physical pool ``[num_pages,
-    page_size, Kv, D]``, ``block_tables`` is ``[B, max_pages]`` of pool
+    ``k_pages``/``v_pages`` are the physical pool ``[num_pages, Kv,
+    page_size, D]`` (head-major, so one head of one page is a contiguous
+    ``(page_size, D)`` tile), ``block_tables`` is ``[B, max_pages]`` of pool
     page ids (rows may be padded with any in-range page id — masking is
     by length, not id), and ``context_lens[b]`` counts the valid tokens
     of row ``b``: position ``p`` of its table is attended iff

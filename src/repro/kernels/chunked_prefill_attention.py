@@ -10,8 +10,12 @@ itself) dominates compute. This kernel computes that: a query chunk
 TPU mapping: grid = (B, Kv, C/bq, S/bk) with the KV axis innermost
 (sequential on TPU), running flash statistics (m, l, acc) in fp32 VMEM
 scratch, output written on the final KV step. Query tiles fold the GQA
-group dim (rows = bq*G); D is padded to a multiple of 128 in ops.py so the
-MXU matmuls are hardware-aligned. Positions arrive via scalar prefetch.
+group dim into rows (row = c*G + g, rows = bq*G) outside the kernel, so
+each KV tile is read once per KV head; D is padded to a multiple of 128 in
+ops.py so the MXU matmuls are hardware-aligned. Positions arrive as VMEM
+tiles: a ``(rows, 1)`` column of query positions and a ``(1, bk)`` row of
+KV positions, so the mask is one broadcast compare (scalar memory only
+serves scalar loads on TPU).
 """
 from __future__ import annotations
 
@@ -23,15 +27,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# contract the last dim of both operands: q [R, D] . k [bk, D] -> [R, bk]
+_NT = (((1,), (1,)), ((), ()))
 
 
-def _kernel(q_pos_ref, kv_pos_ref,           # scalar-prefetch refs (full arrays)
+def _kernel(q_pos_ref, kv_pos_ref,           # [1, rows, 1] / [1, 1, bk] int32
             q_ref, k_ref, v_ref,             # VMEM tiles
             o_ref,                           # output tile
             m_ref, l_ref, acc_ref,           # fp32 scratch
-            *, scale: float, window: int, bq: int, bk: int):
-    bi = pl.program_id(0)
-    qi = pl.program_id(2)
+            *, scale: float, window: int):
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -41,58 +45,56 @@ def _kernel(q_pos_ref, kv_pos_ref,           # scalar-prefetch refs (full arrays
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                            # [bq, G, D]
-    g, d = q.shape[1], q.shape[2]
-    q2 = q.reshape(bq * g, d).astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)        # [rows, D]
     k = k_ref[0, 0].astype(jnp.float32)        # [bk, D]
     v = v_ref[0, 0].astype(jnp.float32)        # [bk, D]
+    s = jax.lax.dot_general(q, k, _NT) * scale  # [rows, bk]
 
-    s = jnp.dot(q2, k.T) * scale               # [bq*G, bk]
-
-    qp = q_pos_ref[bi, pl.ds(qi * bq, bq)]     # [bq]
-    kp = kv_pos_ref[bi, pl.ds(ki * bk, bk)]    # [bk]
-    qp2 = jnp.repeat(qp, g)                    # [bq*G]
-    valid = (kp[None, :] >= 0) & (kp[None, :] <= qp2[:, None])
+    qp = q_pos_ref[0]                          # [rows, 1]
+    kp = kv_pos_ref[0]                         # [1, bk]
+    valid = (kp >= 0) & (kp <= qp)
     if window > 0:
-        valid &= kp[None, :] > qp2[:, None] - window
+        valid &= kp > qp - window
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[:, 0]
-    l_prev = l_ref[:, 0]
-    m_cur = jnp.max(s, axis=1)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(valid, p, 0.0)
+    m_prev = m_ref[:, :1]                      # [rows, 1]
+    l_prev = l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(p, v)
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(p, v)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(ki == nk - 1)
     def _emit():
-        l_fin = l_ref[:, 0]
+        l_fin = l_ref[:, :1]
         safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        out = acc_ref[...] / safe[:, None]
-        o_ref[0, 0] = out.reshape(bq, g, d).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def chunked_prefill_attention_pallas(q, k, v, q_pos, kv_pos, *,
                                      window: int = 0,
                                      block_q: int = 128, block_k: int = 128,
                                      scale: float | None = None,
-                                     interpret: bool = True):
+                                     interpret: bool = False):
     """q [B,C,H,D]; k,v [B,S,Kv,D]; q_pos [B,C]; kv_pos [B,S] -> [B,C,H,D].
 
     Requires C % block_q == 0 and S % block_k == 0 after clamping
-    (ops.py pads inputs and unpads the result).
+    (ops.py pads inputs and unpads the result). ``interpret=True`` runs
+    the kernel in the Pallas interpreter (CPU tests only).
     """
     b, c, h, d = q.shape
     s, kvh = k.shape[1], k.shape[2]
     g = h // kvh
-    qg = q.reshape(b, c, kvh, g, d).transpose(0, 2, 1, 3, 4)  # [B,Kv,C,G,D]
+    # [B,Kv,C*G,D], row = c*G + g
+    qg = (q.reshape(b, c, kvh, g, d).transpose(0, 2, 1, 3, 4)
+          .reshape(b, kvh, c * g, d))
     kt = k.transpose(0, 2, 1, 3)                              # [B,Kv,S,D]
     vt = v.transpose(0, 2, 1, 3)
+    qpos = jnp.repeat(q_pos.astype(jnp.int32), g, axis=1)[:, :, None]
+    kvpos = kv_pos.astype(jnp.int32)[:, None, :]
 
     bq = min(block_q, c)
     bk = min(block_k, s)
@@ -100,30 +102,30 @@ def chunked_prefill_attention_pallas(q, k, v, q_pos, kv_pos, *,
     grid = (b, kvh, c // bq, s // bk)
     rows = bq * g
 
-    kernel = functools.partial(_kernel, scale=scale or d ** -0.5, window=window,
-                               bq=bq, bk=bk)
+    kernel = functools.partial(_kernel, scale=scale or d ** -0.5,
+                               window=window)
     out = pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, bq, g, d),
-                             lambda bi, kvi, qi, ki, *_: (bi, kvi, qi, 0, 0)),
-                pl.BlockSpec((1, 1, bk, d),
-                             lambda bi, kvi, qi, ki, *_: (bi, kvi, ki, 0)),
-                pl.BlockSpec((1, 1, bk, d),
-                             lambda bi, kvi, qi, ki, *_: (bi, kvi, ki, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, bq, g, d),
-                                   lambda bi, kvi, qi, ki, *_: (bi, kvi, qi, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, c, g, d), q.dtype),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, rows, 1), lambda bi, kvi, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, bk), lambda bi, kvi, qi, ki: (bi, 0, ki)),
+            pl.BlockSpec((1, 1, rows, d),
+                         lambda bi, kvi, qi, ki: (bi, kvi, qi, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda bi, kvi, qi, ki: (bi, kvi, ki, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda bi, kvi, qi, ki: (bi, kvi, ki, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, rows, d),
+                               lambda bi, kvi, qi, ki: (bi, kvi, qi, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
+        ],
+        out_shape=jax.ShapeDtypeStruct((b, kvh, c * g, d), q.dtype),
         interpret=interpret,
-    )(q_pos, kv_pos, qg, kt, vt)
-    return out.transpose(0, 2, 1, 3, 4).reshape(b, c, h, d)
+    )(qpos, kvpos, qg, kt, vt)
+    return (out.reshape(b, kvh, c, g, d).transpose(0, 2, 1, 3, 4)
+            .reshape(b, c, h, d))

@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels: padding, alignment, fallback.
 
-``*_auto`` functions pad C/S to block multiples and D to a multiple of 128
-(MXU lane alignment), call the Pallas kernel, and unpad. ``use_pallas=False``
-routes to the pure-jnp oracle (the XLA path used on CPU and in the dry-run).
+``chunked_prefill_attention`` pads C/S to block multiples and D to a
+multiple of 128 (MXU lane alignment), calls the Pallas kernel, and unpads.
+``use_pallas=False`` routes to the pure-jnp oracle (the XLA path used on
+CPU and in the dry-run). The kernels run compiled; ``interpret=True`` is
+for CPU tests that exercise them in the Pallas interpreter.
 """
 from __future__ import annotations
 
@@ -37,7 +39,7 @@ def _pad_pos(x, mult: int):
                                              "block_q", "block_k", "interpret"))
 def chunked_prefill_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
                               use_pallas: bool = False, block_q: int = 128,
-                              block_k: int = 128, interpret: bool = True):
+                              block_k: int = 128, interpret: bool = False):
     if not use_pallas:
         return ref.chunked_prefill_attention_ref(q, k, v, q_pos, kv_pos, window)
     b, c, h, d = q.shape
@@ -63,7 +65,7 @@ def chunked_prefill_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens, *,
-                           use_pallas: bool = False, interpret: bool = True):
+                           use_pallas: bool = False, interpret: bool = False):
     if not use_pallas:
         return ref.paged_decode_attention_ref(q, k_pages, v_pages,
                                               block_tables, context_lens)

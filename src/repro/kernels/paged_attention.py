@@ -6,6 +6,10 @@ index_map dereferences them so the DMA engine streams exactly the pages a
 request owns from HBM into VMEM, ahead of compute. Grid = (B, Kv, pages)
 with pages innermost (sequential), flash statistics accumulated in VMEM
 scratch, output emitted on the final page.
+
+The pool is laid out head-major, ``[P, Kv, page, D]``: one KV head of one
+page is then a contiguous ``(page, D)`` tile whose last two dims are the
+array's own, which is what Mosaic's block-shape rule asks for.
 """
 from __future__ import annotations
 
@@ -17,6 +21,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# contract the last dim of both operands: q [G, D] . k [page, D] -> [G, page]
+_NT = (((1,), (1,)), ((), ()))
 
 
 def _kernel(block_tables_ref, context_lens_ref,   # scalar prefetch
@@ -39,35 +45,35 @@ def _kernel(block_tables_ref, context_lens_ref,   # scalar prefetch
     @pl.when(pi * page < ctx)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jnp.dot(q, k.T) * scale                  # [G, page]
-        pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        k = k_ref[0, 0].astype(jnp.float32)          # [page, D]
+        v = v_ref[0, 0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, _NT) * scale   # [G, page]
+        pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         valid = pos < ctx
         s = jnp.where(valid, s, NEG_INF)
-        m_prev, l_prev = m_ref[:, 0], l_ref[:, 0]
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]  # [G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(p, v)
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(p, v)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(pi == np_ - 1)
     def _emit():
-        l_fin = l_ref[:, 0]
+        l_fin = l_ref[:, :1]
         safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
-                                  context_lens, *, interpret: bool = True):
-    """q [B,H,D]; k/v_pages [P,page,Kv,D]; block_tables [B,max_pages];
-    context_lens [B] -> [B,H,D]."""
+                                  context_lens, *, interpret: bool = False):
+    """q [B,H,D]; k/v_pages [P,Kv,page,D]; block_tables [B,max_pages];
+    context_lens [B] -> [B,H,D]. ``interpret=True`` runs the kernel in the
+    Pallas interpreter (CPU tests only)."""
     b, h, d = q.shape
-    p_total, page, kvh, _ = k_pages.shape
+    p_total, kvh, page, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     g = h // kvh
     qg = q.reshape(b, kvh, g, d)
@@ -76,7 +82,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
     kernel = functools.partial(_kernel, scale=d ** -0.5, page=page)
 
     def kv_index(bi, kvi, pi, bt_ref, cl_ref):
-        return (bt_ref[bi, pi], 0, kvi, 0)
+        return (bt_ref[bi, pi], kvi, 0, 0)
 
     out = pl.pallas_call(
         kernel,
@@ -86,8 +92,8 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_tables,
             in_specs=[
                 pl.BlockSpec((1, 1, g, d),
                              lambda bi, kvi, pi, *_: (bi, kvi, 0, 0)),
-                pl.BlockSpec((1, page, 1, d), kv_index),
-                pl.BlockSpec((1, page, 1, d), kv_index),
+                pl.BlockSpec((1, 1, page, d), kv_index),
+                pl.BlockSpec((1, 1, page, d), kv_index),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d),
                                    lambda bi, kvi, pi, *_: (bi, kvi, 0, 0)),

@@ -35,18 +35,22 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, context_lens):
     """Decode attention over a paged KV cache.
 
     q:            [B, H, D]
-    k/v_pages:    [P, page, Kv, D]
+    k/v_pages:    [P, Kv, page, D] (head-major, the Pallas kernel's layout)
     block_tables: [B, max_pages] int32 (page ids; padding entries arbitrary)
     context_lens: [B] int32
     -> [B, H, D]
     """
     b, h, d = q.shape
-    p, page, kvh, _ = k_pages.shape
+    p, kvh, page, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     g = h // kvh
-    # gather per-request KV: [B, max_pages*page, Kv, D]
-    kk = k_pages[block_tables].reshape(b, max_pages * page, kvh, d)
-    vv = v_pages[block_tables].reshape(b, max_pages * page, kvh, d)
+
+    def gather(pages):
+        """Per-request KV in token order: [B, max_pages*page, Kv, D]."""
+        return (pages[block_tables].transpose(0, 1, 3, 2, 4)
+                .reshape(b, max_pages * page, kvh, d))
+
+    kk, vv = gather(k_pages), gather(v_pages)
     pos = jnp.arange(max_pages * page)[None, :]
     valid = pos < context_lens[:, None]
     qg = q.reshape(b, kvh, g, d).astype(jnp.float32)

@@ -1,10 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+# a CPU rehearsal by design: never take an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) and emit
-memory/cost/roofline analyses. MUST run as its own process (the XLA_FLAGS
-above lock the host device count at first jax init).
+memory/cost/roofline analyses on 512 virtual CPU devices. MUST run as its
+own process (the environment above locks the platform and the host device
+count at first jax init).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-32b \

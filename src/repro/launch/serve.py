@@ -61,6 +61,7 @@ import argparse
 import json
 
 from repro.configs import get_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.serving.api import ServeSpec
 from repro.serving.trace import make_shared_prefix_trace, make_trace
 from repro.workloads import OpenLoopDriver
@@ -248,6 +249,7 @@ def main():
                 f.write(text + "\n")
         return
 
+    configure_compile_cache()
     cfg = get_config(spec.arch, smoke=spec.smoke)
     reqs = _make_trace(args, spec, cfg.vocab_size)
     if spec.executor in ("real", "paged") and spec.s_kv is None:

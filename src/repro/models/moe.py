@@ -133,11 +133,8 @@ def _moe_dispatch_shardmap(p, cfg, xt, gate_w, gate_idx, mesh, rules):
     partial outputs combine with one psum over the expert axis — the only
     collective this MoE layer needs (vs GSPMD all-gathering [E,C,d]
     dispatch buffers)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     e, k, d = cfg.n_experts, cfg.top_k, cfg.d_model
     t = xt.shape[0]

@@ -19,12 +19,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
 
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (dense_init, init_mlp, init_rmsnorm, rmsnorm,
-                                 stack_layers, swiglu)
+                                 swiglu)
 from repro.models.sharding import maybe_shard
 
 
@@ -88,24 +89,46 @@ class DecoderModel:
             p["mlp"] = init_mlp(ks[2], cfg.d_model, cfg.d_ff)
         return p
 
-    def init_params(self, key):
+    def _init_top(self, ks):
         cfg = self.cfg
-        ks = jax.random.split(key, 2 + cfg.n_layers)
         params = {
             "embed": dense_init(ks[0], (cfg.vocab_size, cfg.d_model), scale=0.02),
             "final_norm": init_rmsnorm(cfg.d_model),
         }
         if not cfg.tie_embeddings:
             params["head"] = dense_init(ks[1], (cfg.d_model, cfg.vocab_size))
-        kind = self._stack_kind()
-        if self.n_dense:
-            params["dense_layers"] = stack_layers(
-                [self._init_layer(ks[2 + i], "dense_mlp")
-                 for i in range(self.n_dense)])
-        params["layers"] = stack_layers(
-            [self._init_layer(ks[2 + self.n_dense + i], kind)
-             for i in range(self.n_stack)])
         return params
+
+    def init_params(self, key, dtype=jnp.float32, device=None):
+        """Weights with every leaf in ``dtype``: fp32 master weights by
+        default (training, CPU functional tests); a server passes the
+        model's dtype (``self.dtype``).
+
+        One jitted program makes the stacked layers one at a time
+        (``lax.map``) and casts each tensor as it is made, so the peak
+        holds neither an fp32 copy of a bf16 model nor a second stacked
+        copy. ``device`` places the result (default: JAX's default
+        device)."""
+        cfg = self.cfg
+        kind = self._stack_kind()
+
+        def cast(tree):
+            return jax.tree.map(lambda a: a.astype(dtype), tree)
+
+        def build(key):
+            ks = jax.random.split(key, 2 + cfg.n_layers)
+            params = cast(self._init_top(ks))
+            if self.n_dense:
+                params["dense_layers"] = jax.lax.map(
+                    lambda k: cast(self._init_layer(k, "dense_mlp")),
+                    ks[2:2 + self.n_dense])
+            params["layers"] = jax.lax.map(
+                lambda k: cast(self._init_layer(k, kind)),
+                ks[2 + self.n_dense:])
+            return params
+
+        out = None if device is None else SingleDeviceSharding(device)
+        return jax.jit(build, out_shardings=out)(key)
 
     # ------------------------------------------------------------------
     # cache

@@ -39,6 +39,7 @@ Example::
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 from collections import deque
 from typing import Callable, Deque, Dict, Iterator, List, Optional, Tuple
@@ -386,11 +387,13 @@ class ServeSpec:
         """Build engines, endpoints and router per this spec and wrap
         them in an online :class:`InferenceService`.
 
-        ``executor="real"`` accepts a pre-built ``model``/``params`` pair
-        (otherwise the model is built and initialised here) and requires
-        ``s_kv``.
+        ``executor="real"``/``"paged"`` accept a pre-built
+        ``model``/``params`` pair (otherwise the model is built and
+        initialised here); engines then account KV for ``model.cfg``.
+        ``executor="real"`` requires ``s_kv``.
         """
-        cfg = get_config(self.arch, smoke=self.smoke)
+        cfg = (model.cfg if model is not None
+               else get_config(self.arch, smoke=self.smoke))
         factory = self._executor_factory(cfg, model, params)
         num_kv_blocks = self.effective_num_kv_blocks()
         if self.cluster is not None:
@@ -477,22 +480,34 @@ class ServeSpec:
             raise ValueError(
                 "executor='real' needs s_kv (per-slot KV capacity in "
                 "tokens) — spec.replace(s_kv=max context + headroom)")
+        import jax
         from repro.core.executor import PagedRealExecutor, RealExecutor
+        from repro.models import build_model
         if model is None:
-            import jax
-            from repro.models import build_model
             model = build_model(cfg, exact_moe=True)
-            params = model.init_params(jax.random.PRNGKey(0))
+            if self.executor == "paged":
+                PagedRealExecutor.check_model(model)
+                params = model.init_params(jax.random.PRNGKey(0),
+                                           model.dtype)
+            else:
+                params = model.init_params(jax.random.PRNGKey(0))
         spec = self
 
         if self.executor == "paged":
             self.effective_num_kv_blocks()   # validate sizing up front
+            devices = jax.local_devices()
+            placed: Dict = {}
+            built = itertools.count()
 
             def factory(role):
-                """Fresh paged executor per engine (own block pool)."""
-                # one executor per engine: each owns its own block pool,
-                # sized from EngineConfig.num_kv_blocks at attach_engine
-                return PagedRealExecutor(model, params)
+                """Fresh paged executor per engine (own block pool), one
+                engine per chip: engines go round-robin over the local
+                devices in build order, each pool on its own chip, params
+                placed once per chip (with one device, all share it)."""
+                dev = devices[next(built) % len(devices)]
+                if dev not in placed:
+                    placed[dev] = jax.device_put(params, dev)
+                return PagedRealExecutor(model, placed[dev])
             return factory
 
         def factory(role):

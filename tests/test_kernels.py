@@ -51,7 +51,8 @@ def test_chunked_prefill_ops_padding():
     q, k, v, q_pos, kv_pos = _mk(2, 13, 4, 2, 48, 50, jnp.float32)
     want = ref.chunked_prefill_attention_ref(q, k, v, q_pos, kv_pos, 0)
     got = chunked_prefill_attention(q, k, v, q_pos, kv_pos,
-                                    use_pallas=True, block_q=8, block_k=16)
+                                    use_pallas=True, block_q=8, block_k=16,
+                                    interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
 
@@ -65,8 +66,8 @@ def test_chunked_prefill_ops_padding():
 def test_paged_decode_kernel(b, h, kv, d, pages, page, maxp, dtype):
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (b, h, d), dtype)
-    kp = jax.random.normal(ks[1], (pages, page, kv, d), dtype)
-    vp = jax.random.normal(ks[2], (pages, page, kv, d), dtype)
+    kp = jax.random.normal(ks[1], (pages, kv, page, d), dtype)
+    vp = jax.random.normal(ks[2], (pages, kv, page, d), dtype)
     bt = jax.random.randint(ks[3], (b, maxp), 0, pages)
     cl = jnp.arange(b) * 7 % (maxp * page - 1) + 1
     want = ref.paged_decode_attention_ref(q, kp, vp, bt, cl.astype(jnp.int32))

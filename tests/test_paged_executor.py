@@ -101,8 +101,8 @@ def test_paged_decode_kernel_ragged(page, seed):
     b, h, kv, d, pages, maxp = 4, 4, 2, 32, 24, 6
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (b, h, d))
-    kp = jax.random.normal(ks[1], (pages, page, kv, d))
-    vp = jax.random.normal(ks[2], (pages, page, kv, d))
+    kp = jax.random.normal(ks[1], (pages, kv, page, d))
+    vp = jax.random.normal(ks[2], (pages, kv, page, d))
     bt = np.asarray(rng.integers(0, pages, (b, maxp)), np.int32)
     # ragged: at least one full-page row, the rest partial last pages
     cl = np.asarray([maxp * page]
@@ -121,8 +121,8 @@ def test_paged_decode_padding_id_invariance():
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     b, h, kv, d, pages, page, maxp = 2, 4, 2, 32, 16, 4, 4
     q = jax.random.normal(ks[0], (b, h, d))
-    kp = jax.random.normal(ks[1], (pages, page, kv, d))
-    vp = jax.random.normal(ks[2], (pages, page, kv, d))
+    kp = jax.random.normal(ks[1], (pages, kv, page, d))
+    vp = jax.random.normal(ks[2], (pages, kv, page, d))
     cl = np.asarray([5, 9], np.int32)           # 2 and 3 live pages
     bt = np.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
     base = np.asarray(paged_decode_attention_ref(q, kp, vp, bt, cl))
@@ -319,11 +319,36 @@ def test_paged_extract_inject_roundtrip(setup):
 
     st = se.allocator.block_table("s0")
     dt = de.allocator.block_table("d0")
-    sk = np.asarray(src.k_pool).reshape(model.n_stack, -1,
-                                        cfg.n_kv_heads, cfg.head_dim)
-    dk = np.asarray(dst.k_pool).reshape(model.n_stack, -1,
-                                        cfg.n_kv_heads, cfg.head_dim)
+    def token_rows(pool):
+        """[L, P+1, Kv, page, D] pool -> [L, (P+1)*page, Kv, D] flat slots."""
+        return np.asarray(pool).transpose(0, 1, 3, 2, 4).reshape(
+            model.n_stack, -1, cfg.n_kv_heads, cfg.head_dim)
+
+    sk, dk = token_rows(src.k_pool), token_rows(dst.k_pool)
     for p in range(upto):
         s_idx = st[p // BLOCK] * BLOCK + p % BLOCK
         d_idx = dt[p // BLOCK] * BLOCK + p % BLOCK
         np.testing.assert_array_equal(dk[:, d_idx], sk[:, s_idx])
+
+
+# ---------------------------------------------------------------------------
+# one engine per device
+# ---------------------------------------------------------------------------
+
+def test_one_engine_per_device_subprocess():
+    """Four devices: the paged factory puts a Cronus pair plus two
+    workers on four distinct devices, and every token stream equals the
+    run with all four engines on one device (the CPU rehearsal of
+    ``chip_smoke.py --four-chips``; the test session's jax sees one
+    device, so this runs in its own process). At smoke widths it takes
+    about 20 s on a CPU host, so unlike the multi-minute subprocess
+    oracles it stays in the fast tier."""
+    import os
+    import subprocess
+    import sys
+    script = os.path.join(os.path.dirname(__file__), "helpers",
+                          "check_engine_per_device.py")
+    proc = subprocess.run([sys.executable, script], capture_output=True,
+                          text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "engine devices [0, 1, 2, 3]" in proc.stdout
