@@ -1,0 +1,423 @@
+"""One benchmark run: build the served path, warm it, drive it open-loop on
+the wall clock, and gather what the metrics are read from.
+
+The entry the window drives is the user's: ``ServeSpec(...,
+executor="paged").build(model, params)`` gives an ``InferenceService``.
+The harness keeps its own clock. It submits each request when its due
+time passes, steps the service between sends, and stamps every token on
+the host clock when the engine emits it (``on_token``, which fires after
+the token's logits have been read back to the host, so the device work
+behind it is done). The service's own clock is simulated: each request's
+``arrival`` is set to the service's lagging engine clock at submission,
+so no admission waits on simulated time, and nothing the service reports
+about time is read.
+"""
+from __future__ import annotations
+
+import dataclasses
+import gc
+import json
+import math
+import time
+from collections import defaultdict
+from pathlib import Path
+from typing import Dict, List, Optional
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
+
+from chipbench import traffic, weights
+
+BENCH_DIR = Path(__file__).resolve().parent
+REPO = BENCH_DIR.parent
+# extract_kv gathers the handoff's pages with eager ops, a handful of tiny
+# programs per block count: about 1.1 s per block count to compile cold on
+# a v5e, 0.12 s to load from the cache, and 1-10 s of stall when one first
+# compiles inside the window. Handoffs of up to this many blocks (4096
+# tokens at page 16) are warmed; warming all 512 would take the cold first
+# run past its time limit. A longer one compiles when first seen
+# (``compiles_in_window``)
+EXTRACT_WARM_BLOCKS = 256
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+# ---------------------------------------------------------------------------
+# the cell, from BENCHMARK.json and the files it names
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    chips: int
+    config: dict            # the configuration file
+    mix: dict               # the traffic file
+    setup: dict             # the cell's own file (deployment, rate, check)
+
+    @property
+    def dims(self) -> dict:
+        return dims_of(self.config)
+
+
+def read_bench() -> dict:
+    return json.loads((REPO / "BENCHMARK.json").read_text())
+
+
+def load_cell(name: str, bench: Optional[dict] = None,
+              root: Path = REPO) -> Cell:
+    """The cell ``name`` of ``bench`` (default: the repo's BENCHMARK.json),
+    with its files read from under ``root``."""
+    bench = read_bench() if bench is None else bench
+    (wl,) = [w for w in bench["workloads"] if w["name"] == name]
+    (cf,) = [c for c in bench["configs"] if c["name"] == wl["config"]]
+    config = json.loads((root / cf["file"]).read_text())
+    mix = json.loads((root / "chipbench" / "traffic"
+                      / f"{wl['traffic']}.json").read_text())
+    setup = json.loads((root / "chipbench" / "cells"
+                        / f"{name}.json").read_text())
+    return Cell(name, wl["chips"], config, mix, setup)
+
+
+def dims_of(config: dict) -> dict:
+    """The sizes the benchmark computes with, from a config file written
+    with the source's own key names."""
+    return {"d_model": config["hidden_size"],
+            "n_layers": config["num_hidden_layers"],
+            "n_heads": config["num_attention_heads"],
+            "n_kv_heads": config["num_key_value_heads"],
+            "head_dim": config["head_dim"],
+            "d_ff": config["intermediate_size"],
+            "vocab_size": config["vocab_size"],
+            "rope_theta": float(config["rope_theta"]),
+            "norm_eps": float(config["rms_norm_eps"]),
+            "qk_norm": config["model_type"] == "qwen3",
+            "tie_embeddings": bool(config["tie_word_embeddings"])}
+
+
+def model_config(config: dict):
+    """The program's ``ModelConfig`` for a config file."""
+    from repro.configs.base import ModelConfig
+    d = dims_of(config)
+    if config["torch_dtype"] != "bfloat16":
+        raise ValueError("the paged path serves bfloat16 only")
+    return ModelConfig(
+        name=config["name"], arch_type="dense", n_layers=d["n_layers"],
+        d_model=d["d_model"], n_heads=d["n_heads"],
+        n_kv_heads=d["n_kv_heads"], d_ff=d["d_ff"],
+        vocab_size=d["vocab_size"], head_dim=d["head_dim"],
+        qk_norm=d["qk_norm"], rope_theta=d["rope_theta"],
+        norm_eps=d["norm_eps"], tie_embeddings=d["tie_embeddings"],
+        dtype="bfloat16")
+
+
+# ---------------------------------------------------------------------------
+# what a run records
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass
+class Call:
+    kind: str               # prefill | decode | extract | inject
+    engine: str
+    t0: float
+    t1: float
+    shape: tuple            # prefill (chunk, ctx, completes); decode ctxs;
+                            # extract/inject (tokens,)
+
+
+class Recorder:
+    """Host-clock stamps and counts; one per process."""
+
+    def __init__(self):
+        self.due: Dict[str, float] = {}
+        self.sent: Dict[str, float] = {}
+        self.stamps: Dict[str, List[float]] = defaultdict(list)
+        self.calls: List[Call] = []
+        self.kv_share: List[tuple] = []       # (time, share)
+        self.compiles: List[tuple] = []       # (time, event)
+        jax.monitoring.register_event_duration_secs_listener(self._on_dur)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_dur(self, event: str, duration: float, **_) -> None:
+        if event == COMPILE_EVENT:
+            self.compiles.append((time.perf_counter(), "compile"))
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == CACHE_HIT_EVENT:
+            self.compiles.append((time.perf_counter(), "cache_load"))
+
+    def clear_traffic(self) -> None:
+        self.due.clear()
+        self.sent.clear()
+        self.stamps.clear()
+        self.calls.clear()
+        self.kv_share.clear()
+
+
+def instrument(svc, rec: Recorder) -> None:
+    """Stamp tokens on the host clock, and time and annotate each call into
+    the model step, without changing what the program does."""
+    for eng in svc.engines:
+        inner = eng.on_token
+
+        def on_token(req, token, t, inner=inner):
+            rec.stamps[req.req_id].append(time.perf_counter())
+            if inner is not None:
+                inner(req, token, t)
+        eng.on_token = on_token
+        _wrap_executor(eng.executor, eng.name, rec)
+
+
+def _wrap_executor(ex, engine: str, rec: Recorder) -> None:
+    prefill, decode = ex.prefill_chunk, ex.decode
+    extract, inject = ex.extract_kv, ex.inject_kv
+
+    def prefill_chunk(slot, tokens, ctx_len, completes, enc_emb=None):
+        t0 = time.perf_counter()
+        with TraceAnnotation(f"{engine}.prefill_chunk"):
+            out = prefill(slot, tokens, ctx_len, completes, enc_emb=enc_emb)
+        rec.calls.append(Call("prefill", engine, t0, time.perf_counter(),
+                              (len(tokens), int(ctx_len), bool(completes))))
+        return out
+
+    def decode_(slot_tokens, slot_lens):
+        t0 = time.perf_counter()
+        with TraceAnnotation(f"{engine}.decode"):
+            out = decode(slot_tokens, slot_lens)
+        rec.calls.append(Call("decode", engine, t0, time.perf_counter(),
+                              tuple(int(slot_lens[s]) + 1
+                                    for s in slot_tokens)))
+        return out
+
+    def extract_kv(slot, upto):
+        t0 = time.perf_counter()
+        with TraceAnnotation(f"{engine}.extract_kv"):
+            out = extract(slot, upto)
+        rec.calls.append(Call("extract", engine, t0, time.perf_counter(),
+                              (int(upto),)))
+        return out
+
+    def inject_kv(slot, payload, upto):
+        t0 = time.perf_counter()
+        with TraceAnnotation(f"{engine}.inject_kv"):
+            out = inject(slot, payload, upto)
+        rec.calls.append(Call("inject", engine, t0, time.perf_counter(),
+                              (int(upto),)))
+        return out
+
+    ex.prefill_chunk, ex.decode = prefill_chunk, decode_
+    ex.extract_kv, ex.inject_kv = extract_kv, inject_kv
+
+
+# ---------------------------------------------------------------------------
+# build and warm
+# ---------------------------------------------------------------------------
+
+def build_service(cell: Cell, seed: int, device):
+    """Weights from the seed on ``device``, then the service as a user
+    builds it."""
+    from repro.models import build_model
+    from repro.serving.api import ServeSpec
+    params = weights.make_params(seed, cell.dims, device)
+    model = build_model(model_config(cell.config))
+    spec = ServeSpec(arch=cell.config["arch_id"], executor="paged",
+                     **cell.mix["deployment"], **cell.setup["serve"])
+    return spec.build(model=model, params=params), params
+
+
+def _pow2(n: int, lo: int) -> int:
+    b = lo
+    while b < n:
+        b *= 2
+    return b
+
+
+def _buckets(lo: int, hi: int) -> List[int]:
+    out, b = [], lo
+    while True:
+        out.append(b)
+        if b >= hi:
+            return out
+        b *= 2
+
+
+def reachable_shapes(cell: Cell, page: int, max_tokens: int,
+                     max_slots: int) -> dict:
+    """Bucket shapes the cell's length bounds can reach, as the executor
+    buckets them: prefill (chunk, pages), decode (batch, pages), handoff
+    payload blocks and inject rows."""
+    max_in, max_out = traffic.length_bounds(cell.mix)
+    prefill = set()
+    for c in range(1, min(max_tokens, max_in) + 1):
+        cb = _pow2(c, 16)
+        lo = _pow2(math.ceil(c / page), 4)
+        for pb in _buckets(lo, _pow2(math.ceil(max_in / page), 4)):
+            prefill.add((cb, pb))
+    pb_dec = _buckets(4, _pow2(math.ceil((max_in + max_out) / page), 4))
+    decode = [(bb, pb) for bb in _buckets(4, _pow2(max_slots, 4))
+              for pb in pb_dec]
+    return {"prefill": sorted(prefill), "decode": decode,
+            "extract_blocks": list(range(1, min(math.ceil(max_in / page),
+                                                EXTRACT_WARM_BLOCKS) + 1)),
+            "inject_rows": _buckets(page, _pow2(max_in, page))}
+
+
+def warm_shapes(svc, cell: Cell, log=lambda msg: None) -> dict:
+    """Run every reachable program shape once on throwaway inputs that
+    write only the trash page, exactly as the executor calls it, and read
+    one logits row back the way the executor does. Pools are left as they
+    were (the outputs are dropped). ``log`` gets one line per phase.
+
+    The executor's public calls need a request resident in a slot, so the
+    warm-up calls its jitted step functions (and repeats ``extract_kv``'s
+    gather) directly. If the program changes what it compiles, the window
+    compiles it: ``compiles_in_window`` shows it, and a CPU test asserts
+    that the window of a tiny cell compiles nothing."""
+    from repro.core.executor import robust_greedy
+    counts = {}
+    seen_extract = False
+
+    def phase(name, items, call):
+        t = time.perf_counter()
+        for it in items:
+            call(*it) if isinstance(it, tuple) else call(it)
+        counts[name] = counts.get(name, 0) + len(items)
+        log(f"warm {name}: {len(items)} shapes in "
+            f"{time.perf_counter() - t:.1f} s")
+
+    for eng in svc.engines:
+        ex, ecfg = eng.executor, eng.ecfg
+        page, trash = ex.page, ex._trash
+        shapes = reachable_shapes(cell, page, ecfg.max_batched_tokens,
+                                  ecfg.max_slots)
+
+        def prefill(cb, pb, ex=ex, page=page, trash=trash):
+            pos = np.full((1, cb), -1, np.int32)
+            pos[0, 0] = 0
+            logits, _, _ = ex._prefill_fn(
+                ex.params, ex.k_pool, ex.v_pool, np.zeros((1, cb), np.int32),
+                pos, np.full((cb,), trash * page, np.int32),
+                np.full((pb,), trash, np.int32), np.int32(1))
+            robust_greedy(logits[0, 0])
+
+        def decode(bb, pb, ex=ex, page=page, trash=trash):
+            z = np.zeros((bb,), np.int32)
+            logits, _, _ = ex._decode_fn(
+                ex.params, ex.k_pool, ex.v_pool, z, z,
+                np.full((bb,), trash * page, np.int32),
+                np.full((bb, pb), trash, np.int32), z)
+            robust_greedy(logits[0])
+
+        def extract(n, ex=ex):
+            idx = jnp.zeros((n,), jnp.int32)
+            ex.k_pool[:, idx].transpose(0, 1, 3, 2, 4).block_until_ready()
+
+        def inject(nb, ex=ex, page=page, trash=trash):
+            l_dim, _, kvh, _, hd = ex.k_pool.shape
+            rows = np.zeros((l_dim, nb, kvh, hd), jnp.bfloat16)
+            k, _ = ex._inject_fn(ex.k_pool, ex.v_pool, rows, rows,
+                                 np.full((nb,), trash * page, np.int32))
+            k.block_until_ready()
+
+        if not ecfg.decode_only:
+            phase(f"{eng.name}.prefill", shapes["prefill"], prefill)
+        if not ecfg.prefill_only:
+            phase(f"{eng.name}.decode", shapes["decode"], decode)
+        if ecfg.prefill_only and not seen_extract:
+            phase(f"{eng.name}.extract_kv", shapes["extract_blocks"], extract)
+            seen_extract = True
+        if not ecfg.prefill_only and _pair_of(svc, eng) is not None:
+            phase(f"{eng.name}.inject_kv", shapes["inject_rows"], inject)
+    return counts
+
+
+def _pair_of(svc, eng):
+    """The Cronus pair endpoint that holds ``eng``, or None."""
+    from repro.cluster.pair import CronusPairEndpoint
+    for ep in svc.endpoints:
+        if isinstance(ep, CronusPairEndpoint) and eng in ep.engines:
+            return ep
+    return None
+
+
+def decode_engine(svc):
+    """The engine that decodes (a pair's CPI, or the worker)."""
+    return svc.endpoints[0].engines[-1]
+
+
+# ---------------------------------------------------------------------------
+# the open loop
+# ---------------------------------------------------------------------------
+
+def make_requests(planned, prefix: str = "r"):
+    from repro.core.request import Request
+    return [Request(req_id=f"{prefix}{p.index}", prompt=p.prompt,
+                    output_len=p.output_len) for p in planned]
+
+
+def wait_device(svc) -> None:
+    """Wait for every program the service has launched."""
+    for eng in svc.engines:
+        eng.executor.k_pool.block_until_ready()
+        eng.executor.v_pool.block_until_ready()
+
+
+def drive(svc, reqs, due_at, rec: Recorder, until: float,
+          on_time=None) -> None:
+    """Send each request once its due time (host clock) has passed, and
+    step the service in between, until ``until``. ``on_time(now)`` is
+    called every round (the profiler uses it)."""
+    dec = decode_engine(svc)
+    i, n = 0, len(reqs)
+    while True:
+        now = time.perf_counter()
+        if now >= until:
+            return
+        if on_time is not None:
+            on_time(now)
+        while i < n and due_at[i] <= now:
+            r = reqs[i]
+            r.arrival = min(e.clock for e in svc.engines)
+            r.metrics.arrival = r.arrival
+            with TraceAnnotation("submit"):
+                svc.submit(r)
+            rec.due[r.req_id] = due_at[i]
+            rec.sent[r.req_id] = time.perf_counter()
+            i += 1
+        with TraceAnnotation("service.step"):
+            progressed = svc.step()
+        alloc = dec.allocator
+        rec.kv_share.append((time.perf_counter(),
+                             1.0 - alloc.num_free / alloc.num_blocks))
+        if not progressed:
+            nxt = due_at[i] if i < n else until
+            with TraceAnnotation("wait_for_arrival"):
+                time.sleep(max(0.0, min(nxt, until) - time.perf_counter()))
+
+
+def backlog(rec: Recorder, t: float) -> int:
+    """Requests sent by ``t`` that had no first token at ``t``."""
+    return sum(1 for rid, sent in rec.sent.items() if sent <= t
+               and not (rec.stamps.get(rid) and rec.stamps[rid][0] <= t))
+
+
+def free_service(svc, params, reqs) -> None:
+    """Free the program's device state (pools, payloads, weights) now,
+    whatever still refers to it."""
+    for r in reqs:
+        r.kv_payload = None
+    for eng in svc.engines:
+        eng.executor.k_pool.delete()
+        eng.executor.v_pool.delete()
+    for leaf in jax.tree.leaves(params):
+        leaf.delete()
+    gc.collect()
+
+
+def peak_bytes() -> int:
+    """Peak bytes in use on the fullest device (0 where the backend does
+    not report memory)."""
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in jax.local_devices())

@@ -1,0 +1,5 @@
+"""The benchmark's tests import ``chipbench`` from the repository root."""
+import os
+import sys
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
