@@ -177,6 +177,7 @@ class CronusPairEndpoint(Endpoint):
                 self.ppi.completed_prefills = [
                     (t, v) for t, v in self.ppi.completed_prefills
                     if v.req_id != rid]
+                self._trace_handoff_left(rid)
             orig.partial_len = 0
             orig.kv_payload = None
             orig.first_token = None
@@ -213,6 +214,7 @@ class CronusPairEndpoint(Endpoint):
                 self.ppi.completed_prefills = [
                     (t, v) for t, v in self.ppi.completed_prefills
                     if v.req_id != rid]
+                self._trace_handoff_left(rid)
                 orig.partial_len = view.context_len
                 orig.context_len = view.context_len
                 orig.kv_payload = view.kv_payload
@@ -245,6 +247,13 @@ class CronusPairEndpoint(Endpoint):
                 self._offloaded.discard(r.req_id)
                 displaced.append(r)
         return displaced
+
+    def _trace_handoff_left(self, rid: str) -> None:
+        """A completed PPI prefill left the pair unpumped (detach): its
+        payload's ``kv_in_flight`` wait ends here."""
+        if self.ppi.tracer is not None:
+            self.ppi.tracer.async_close(self.ppi.trace_track, "kv_in_flight",
+                                        rid, {"left": True})
 
     def _find_view(self, rid: str):
         for r in self.ppi.slots:
@@ -290,6 +299,8 @@ class CronusPairEndpoint(Endpoint):
                 orig.metrics.cancel_time = self.ppi.clock
                 if self.ppi.tracer is not None:
                     tracer = self.ppi.tracer
+                    tracer.async_close(self.ppi.trace_track, "kv_in_flight",
+                                       rid, {"cancelled": True})
                     tracer.instant(self.ppi.trace_track, "cancel",
                                    self.ppi.clock, {"req": rid})
                     tracer.async_end(tracer.control, "request",

@@ -38,6 +38,7 @@ from repro.core.engine import Engine
 from repro.core.metrics import aggregate
 from repro.core.request import ReqState, Request
 from repro.kvcache.transfer import TransferEngine
+from repro.obs.tracer import NO_SPAN
 
 
 @dataclasses.dataclass(frozen=True)
@@ -464,13 +465,27 @@ class ClusterRuntime:
         engine (or, if the whole cluster is idle, jump every clock to the
         next event time). Returns False only when no progress is possible
         at all — the online facade (``repro.serving.api``) drives this
-        incrementally; ``run`` below is the batch replay over it."""
-        self._dispatch(pending)
+        incrementally; ``run`` below is the batch replay over it. On a
+        host-clock tracer the round is the span ``tick`` on the control
+        lane, parent of ``dispatch``, ``pump`` and the stepped engine's
+        ``iter``."""
+        tracer = self.tracer
+        if tracer is None:
+            return self._tick(pending, None)
+        with tracer.span(tracer.control, "tick"):
+            return self._tick(pending, tracer)
+
+    def _tick(self, pending: deque, tracer) -> bool:
+        with (tracer.span(tracer.control, "dispatch")
+              if tracer is not None else NO_SPAN):
+            self._dispatch(pending)
 
         # ---- internal handoffs; fire what they posted --------------
-        for ep in self.endpoints:
-            ep.pump(self)
-        self._drain_events()
+        with (tracer.span(tracer.control, "pump")
+              if tracer is not None else NO_SPAN):
+            for ep in self.endpoints:
+                ep.pump(self)
+            self._drain_events()
 
         # ---- advance the globally-lagging runnable engine ----------
         for eng in sorted(self.engines, key=lambda e: e.clock):

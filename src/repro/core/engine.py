@@ -30,7 +30,15 @@ import numpy as np
 from repro.core.balancer import CPIStats
 from repro.core.request import ReqState, Request
 from repro.kvcache import BlockAllocator
+from repro.obs.tracer import NO_SPAN
 from repro.scheduling import IterationPlan, SchedulerView, make_scheduler
+
+
+def payload_bytes(payload) -> int:
+    """Bytes of the arrays a KV payload carries (nested dicts)."""
+    if isinstance(payload, dict):
+        return sum(payload_bytes(v) for v in payload.values())
+    return int(getattr(payload, "nbytes", 0))
 
 
 @dataclasses.dataclass
@@ -103,15 +111,20 @@ class Engine:
             self.on_token(req, token, self.clock)
 
     def _trace_gauges(self, tracer):
-        """Per-iteration gauge samples (tracing on only): queue depth,
-        free KV blocks, trailing busy fraction."""
+        """Per-iteration gauge samples (tracing on only): queue depth and
+        free KV blocks."""
         resident = sum(1 for r in self.slots if r is not None)
         tracer.counter(self.trace_track, "queue_depth", self.clock,
                        {"queued": len(self.queue), "resident": resident})
         tracer.counter(self.trace_track, "free_kv_blocks", self.clock,
                        {"free": self.allocator.num_free})
-        tracer.counter(self.trace_track, "busy_frac", self.clock,
-                       {"busy": self.busy_fraction()})
+
+    def _trace_dequeued(self, req: Request) -> None:
+        """``req`` left this engine's queue without a slot (cancel, drain,
+        migration): its ``queue`` wait ends there."""
+        if self.tracer is not None:
+            self.tracer.async_close(self.trace_track, "queue", req.req_id,
+                                    {"left": True})
 
     # ------------------------------------------------------------------
     # busy-time accounting (autoscaler utilization signal)
@@ -148,6 +161,8 @@ class Engine:
             self.clock = max(self.clock, now)
         req.state = ReqState.WAITING
         self.queue.append(req)
+        if self.tracer is not None:
+            self.tracer.async_open(self.trace_track, "queue", req.req_id)
 
     def _view(self) -> SchedulerView:
         return SchedulerView(clock=self.clock, slots=self.slots,
@@ -170,10 +185,16 @@ class Engine:
         output token."""
         slot = self._free_slot()
         assert slot is not None, "plan admitted with no free slot"
+        if self.tracer is not None:
+            # every admission, on every engine (a Cronus CPI's included),
+            # ends the request's queue wait here (host clock only)
+            self.tracer.async_close(self.trace_track, "queue", req.req_id)
         if req.metrics.service_start_time is None:
             # first slot admission anywhere (PPI prefill views share the
             # metrics object; preemption-recompute re-placements keep the
-            # original): the queueing/service boundary of TTFT
+            # original): the queueing/service boundary of TTFT, at this
+            # engine's simulated clock (the trace stamps it on the
+            # tracer's clock)
             req.metrics.service_start_time = self.clock
             if self.tracer is not None:
                 self.tracer.instant(self.trace_track, "service_start",
@@ -249,6 +270,8 @@ class Engine:
         req.state = ReqState.WAITING
         req.ready_time = self.clock
         self.queue.appendleft(req)
+        if self.tracer is not None:
+            self.tracer.async_open(self.trace_track, "queue", req.req_id)
 
     def _apply(self, plan: IterationPlan):
         for r in plan.preempt:
@@ -318,20 +341,46 @@ class Engine:
     # one iteration
     # ------------------------------------------------------------------
     def step(self) -> float:
-        """Execute one iteration; returns its simulated duration (s)."""
+        """Execute one iteration; returns its simulated duration (s). On
+        a host-clock tracer the iteration is the span ``iter``, parent of
+        the spans of its scheduling and executor calls."""
         tracer = self.tracer
+        if tracer is None or not tracer.host_clock:
+            return self._step(tracer, None)
+        with tracer.span(self.trace_track, "iter") as it:
+            return self._step(tracer, it)
+
+    def _trace_iter(self, tracer, it, t_start: float, args: dict) -> None:
+        """The iteration on the trace: a simulated-clock span, or the args
+        of the open host-clock ``iter`` span with the simulated interval
+        it stood for; then the gauges."""
+        if it is None:
+            tracer.complete(self.trace_track, "iter", t_start, self.clock,
+                            args)
+        else:
+            it.set(sim_t0=t_start, sim_t1=self.clock, **args)
+        self._trace_gauges(tracer)
+
+    def _step(self, tracer, it) -> float:
         t_start = self.clock
-        plan = self.scheduler.plan(self._view())
-        if tracer is not None:
-            n_admit, n_preempt = len(plan.admit), len(plan.preempt)
-        self._apply(plan)
+        with (tracer.span(self.trace_track, "schedule")
+              if tracer is not None else NO_SPAN):
+            plan = self.scheduler.plan(self._view())
+            if tracer is not None:
+                n_admit, n_preempt = len(plan.admit), len(plan.preempt)
+            self._apply(plan)
 
         # --- ingest pending KV transfers (overlapped with compute) -------
         transfer_time = 0.0
         ttft_at_ingest: List[Request] = []
         for r in self.slots:
             if r and r.state == ReqState.TRANSFER:
-                self.executor.inject_kv(r.slot, r.kv_payload, r.context_len)
+                with (tracer.span(self.trace_track, "inject_kv",
+                                  req=r.req_id, tokens=r.context_len,
+                                  bytes=payload_bytes(r.kv_payload))
+                      if tracer is not None else NO_SPAN):
+                    self.executor.inject_kv(r.slot, r.kv_payload,
+                                            r.context_len)
                 if not r.local_payload:   # decode-offload: KV never moved
                     # the payload holds the PPI's partial_len tokens; a
                     # prefix-cache hit may have advanced context_len past
@@ -411,14 +460,13 @@ class Engine:
                     r.metrics.finish_time = self.clock
                     self._finish(r)
             self._record_work(transfer_time)
-            if tracer is not None and transfer_time > 0.0:
-                tracer.complete(
-                    self.trace_track, "iter", t_start, self.clock,
+            if tracer is not None and (it is not None or transfer_time > 0.0):
+                self._trace_iter(
+                    tracer, it, t_start,
                     {"n_decode": 0, "prefill_tokens": 0,
                      "migrated_prefill_tokens": 0, "n_admit": n_admit,
                      "n_preempt": n_preempt, "transfer_s": transfer_time,
                      "chunks": []})
-                self._trace_gauges(tracer)
             return transfer_time
 
         # --- execute prefill chunks (possibly several requests) -----------
@@ -435,9 +483,12 @@ class Engine:
         for r, n in chunks:
             tokens = r.prompt[r.context_len: r.context_len + n]
             completes = r.context_len + n >= r.input_len
-            first = self.executor.prefill_chunk(
-                r.slot, tokens, r.context_len, completes,
-                enc_emb=r.enc_emb if r.context_len == 0 else None)
+            with (tracer.span(self.trace_track, "prefill_chunk",
+                              req=r.req_id, tokens=n)
+                  if tracer is not None else NO_SPAN):
+                first = self.executor.prefill_chunk(
+                    r.slot, tokens, r.context_len, completes,
+                    enc_emb=r.enc_emb if r.context_len == 0 else None)
             r.context_len += n
             if completes:
                 first_tokens[r.req_id] = first
@@ -449,7 +500,10 @@ class Engine:
                 # input_len + (#generated - 1)
                 slot_tokens[r.slot] = r.generated[-1]
                 slot_lens[r.slot] = r.total_ctx - 1
-            new_tokens = self.executor.decode(slot_tokens, slot_lens)
+            with (tracer.span(self.trace_track, "decode",
+                              n=len(decode_reqs))
+                  if tracer is not None else NO_SPAN):
+                new_tokens = self.executor.decode(slot_tokens, slot_lens)
 
         # --- timing -------------------------------------------------------
         decode_ctx_sum = float(sum(r.total_ctx for r in decode_reqs))
@@ -459,15 +513,14 @@ class Engine:
         self.clock += duration
         self._record_work(duration)
         if tracer is not None:
-            tracer.complete(
-                self.trace_track, "iter", t_start, self.clock,
+            self._trace_iter(
+                tracer, it, t_start,
                 {"n_decode": len(decode_reqs),
                  "decode_ctx": decode_ctx_sum,
                  "prefill_tokens": prefill_tokens,
                  "migrated_prefill_tokens": migrated_tokens,
                  "n_admit": n_admit, "n_preempt": n_preempt,
                  "transfer_s": transfer_time, "chunks": chunk_info})
-            self._trace_gauges(tracer)
         for r in ttft_at_ingest:
             r.metrics.first_token_time = self.clock
             if tracer is not None:
@@ -571,6 +624,7 @@ class Engine:
         for i, r in enumerate(self.queue):
             if r.req_id == req_id:
                 del self.queue[i]
+                self._trace_dequeued(r)
                 self.allocator.free(req_id)    # no-op when nothing is owned
                 return r
         for r in self.slots:
@@ -606,6 +660,7 @@ class Engine:
         displaced = []
         while self.queue:
             r = self.queue.popleft()
+            self._trace_dequeued(r)
             r.kv_payload = None
             r.local_payload = False
             r.first_token = None
@@ -633,6 +688,7 @@ class Engine:
                 displaced.append(self._extract_resident(r))
         while self.queue:
             r = self.queue.popleft()
+            self._trace_dequeued(r)
             self.allocator.free(r.req_id)   # no-op when nothing is owned
             if r.kv_payload is None:
                 # plain queued arrival: nothing engine-local to preserve
@@ -696,8 +752,18 @@ class Engine:
         """Prefill-only instance: extract KV and release the slot; the
         orchestrator routes the payload to the decode instance. With
         prefix caching the prefilled prompt is registered, so repeated
-        shared prefixes shorten the PPI's split-prefill portion too."""
-        req.kv_payload = self.executor.extract_kv(req.slot, req.context_len)
+        shared prefixes shorten the PPI's split-prefill portion too.
+        Traced, the payload is in flight from here to its delivery."""
+        tracer = self.tracer
+        with (tracer.span(self.trace_track, "extract_kv", req=req.req_id,
+                          tokens=req.context_len)
+              if tracer is not None else NO_SPAN) as sp:
+            req.kv_payload = self.executor.extract_kv(req.slot,
+                                                      req.context_len)
+            if tracer is not None:
+                sp.set(bytes=payload_bytes(req.kv_payload))
+        if tracer is not None:
+            tracer.async_open(self.trace_track, "kv_in_flight", req.req_id)
         if self.allocator.prefix_cache:
             self.allocator.free(req.req_id,
                                 cache_tokens=req.prompt[:req.context_len])

@@ -659,7 +659,16 @@ class PagedRealExecutor:
         self.buckets.record("decode", bb, pb)
         logits, self.k_pool, self.v_pool = self._decode_fn(
             self.params, self.k_pool, self.v_pool, tok, pos, widx, tbl, ctx)
-        return {s: robust_greedy(logits[i]) for i, s in enumerate(slots)}
+        tracer = self._engine.tracer
+        if tracer is None:
+            return {s: robust_greedy(logits[i]) for i, s in enumerate(slots)}
+        # traced: wait for the step on its own, so that the row reads
+        # after it time only the host round trips
+        track = self._engine.trace_track
+        with tracer.span(track, "decode.wait"):
+            logits.block_until_ready()
+        with tracer.span(track, "readback", rows=n):
+            return {s: robust_greedy(logits[i]) for i, s in enumerate(slots)}
 
     # ------------------------------------------------------------------
     # KV handoff: block-granular, sized by the partial prefill

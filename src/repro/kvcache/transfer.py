@@ -130,9 +130,12 @@ class TransferEngine:
                 h.state = "cancelled"
                 self.n_cancelled += 1
                 if tracer is not None:
-                    tracer.instant(tracer.track_for(h.src), "kv_cancelled",
-                                   t, {"req": h.req_id, "kind": h.kind,
-                                       "dst": h.dst})
+                    src = tracer.track_for(h.src)
+                    tracer.instant(src, "kv_cancelled", t,
+                                   {"req": h.req_id, "kind": h.kind,
+                                    "dst": h.dst})
+                    tracer.async_close(src, "kv_in_flight", h.req_id,
+                                       {"cancelled": True})
                 return
             h.state = "delivered"
             self.tokens_moved += h.n_tokens
@@ -151,6 +154,10 @@ class TransferEngine:
                                 t, fid, args)
                 tracer.counter(tracer.control, "transfer_tokens", t,
                                {h.kind: self.tokens_by_kind[h.kind]})
+                # the payload's flight, open since the source's extract_kv
+                # (host clock only), ends at its delivery
+                tracer.async_close(tracer.track_for(h.src), "kv_in_flight",
+                                   h.req_id)
             deliver(r)
 
         if self._runtime is not None:

@@ -648,10 +648,16 @@ class InferenceService:
         engine (grouped per endpoint), and thread it through the
         runtime, every engine, and each engine's allocator. Idempotent —
         a second call returns the live tracer. Call before submitting
-        work for a complete record."""
+        work for a complete record.
+
+        The clock follows the executor: a ``null`` executor's run is
+        simulated, so its trace keeps the simulated clock; ``real`` and
+        ``paged`` executors run real compute, so theirs is stamped on the
+        host clock (``time.perf_counter``)."""
         if self.runtime.tracer is None:
             from repro.obs import Tracer
-            self.runtime.tracer = Tracer()
+            host = self.spec is not None and self.spec.executor != "null"
+            self.runtime.tracer = Tracer(host_clock=host)
             for ep in self.runtime.endpoints:
                 self._wire_trace(ep)
         return self.runtime.tracer
