@@ -665,7 +665,10 @@ class PagedRealExecutor:
         # traced: wait for the step on its own, so that the row reads
         # after it time only the host round trips
         track = self._engine.trace_track
-        with tracer.span(track, "decode.wait"):
+        # the pages the decode kernel copies, against the padded table
+        live = sum(math.ceil(c / page) for c in ctx[:n].tolist())
+        with tracer.span(track, "decode.wait", live_pages=live,
+                         table_pages=bb * pb):
             logits.block_until_ready()
         with tracer.span(track, "readback", rows=n):
             return {s: robust_greedy(logits[i]) for i, s in enumerate(slots)}

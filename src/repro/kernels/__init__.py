@@ -22,11 +22,17 @@ context_lens, *, use_pallas=False)``
     ``k_pages``/``v_pages`` are the physical pool ``[num_pages, Kv,
     page_size, D]`` (head-major, so one head of one page is a contiguous
     ``(page_size, D)`` tile), ``block_tables`` is ``[B, max_pages]`` of pool
-    page ids (rows may be padded with any in-range page id — masking is
-    by length, not id), and ``context_lens[b]`` counts the valid tokens
-    of row ``b``: position ``p`` of its table is attended iff
-    ``p < context_lens[b]``, so a partial last page is handled by length
-    alone. No sliding-window support.
+    page ids, and ``context_lens[b]`` counts the valid tokens of row
+    ``b``: position ``p`` of its table is attended iff ``p <
+    context_lens[b]``, so a partial last page is handled by length alone.
+    The Pallas kernel runs one grid step per row for all KV heads and
+    copies only the row's live pages, ``ceil(context_lens[b] /
+    page_size)`` of them, HBM -> VMEM in double-buffered blocks of ``ppb``
+    pages (1024 tokens' worth, derived from the page size, capped at
+    ``max_pages``): table entries past the live pages are never read, so
+    rows may be padded with any in-range page id, and a row of length 0
+    yields zeros. Slots past the context in the last page may hold
+    anything, NaN included. No sliding-window support.
 
 ``paged_decode_attention_pallas`` / ``chunked_prefill_attention_pallas``
     The raw Pallas kernels behind ``use_pallas=True`` — fixed tile-size

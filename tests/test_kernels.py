@@ -57,26 +57,82 @@ def test_chunked_prefill_ops_padding():
                                atol=3e-5, rtol=3e-5)
 
 
-@pytest.mark.parametrize("b,h,kv,d,pages,page,maxp", [
-    (2, 8, 2, 64, 16, 8, 4),
-    (3, 4, 4, 32, 8, 16, 3),
-    (1, 8, 1, 128, 32, 8, 8),
+@pytest.mark.parametrize("b,h,kv,d,pages,page,maxp,lens", [
+    pytest.param(2, 8, 2, 64, 16, 8, 4, None, id="2-8-2-64-16-8-4"),
+    pytest.param(3, 4, 4, 32, 8, 16, 3, None, id="3-4-4-32-8-16-3"),
+    pytest.param(1, 8, 1, 128, 32, 8, 8, None, id="1-8-1-128-32-8-8"),
+    # qwen2 widths, page 16: 3 compute blocks of 64 pages, the last
+    # partial; an empty padded lane; a row of one token; a block of one
+    pytest.param(4, 28, 4, 128, 48, 16, 160, (2500, 1, 0, 1025),
+                 id="qwen2-page16-blocks"),
+    # qwen3's GQA group 8
+    pytest.param(2, 64, 8, 128, 24, 16, 20, (300, 17), id="qwen3-group8"),
+    # page 4: 256 pages per compute block
+    pytest.param(4, 8, 2, 128, 96, 4, 320, (1280, 1025, 0, 1),
+                 id="page4-blocks"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_kernel(b, h, kv, d, pages, page, maxp, dtype):
+def test_paged_decode_kernel(b, h, kv, d, pages, page, maxp, lens, dtype):
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (b, h, d), dtype)
     kp = jax.random.normal(ks[1], (pages, kv, page, d), dtype)
     vp = jax.random.normal(ks[2], (pages, kv, page, d), dtype)
     bt = jax.random.randint(ks[3], (b, maxp), 0, pages)
-    cl = jnp.arange(b) * 7 % (maxp * page - 1) + 1
-    want = ref.paged_decode_attention_ref(q, kp, vp, bt, cl.astype(jnp.int32))
-    got = paged_decode_attention_pallas(q, kp, vp, bt, cl.astype(jnp.int32),
-                                        interpret=True)
+    cl = (jnp.arange(b) * 7 % (maxp * page - 1) + 1 if lens is None
+          else jnp.array(lens)).astype(jnp.int32)
+    want = ref.paged_decode_attention_ref(q, kp, vp, bt, cl)
+    # an empty row attends to nothing: the kernel writes zeros there
+    want = jnp.where(cl[:, None, None] == 0, 0.0, want.astype(jnp.float32))
+    got = paged_decode_attention_pallas(q, kp, vp, bt, cl, interpret=True)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("page,maxp,lens", [
+    (16, 160, (2100, 1, 0, 257)),
+    (4, 320, (1100, 2, 0, 64)),
+])
+def test_paged_decode_reads_live_pages_only(page, maxp, lens):
+    """Every pool slot that no row reads as live holds NaN: whole pages past
+    each row's ``ceil(ctx / page)``, the tail of each partial last page,
+    and the trash page (the last id) that pads the tables. The kernel must
+    copy and attend to the live slots alone, so its output stays finite
+    and matches the reference on the unpoisoned pool."""
+    b, h, kv, d = len(lens), 8, 2, 128
+    n_live = [-(-n // page) for n in lens]
+    pages = sum(n_live) + 8 + 1            # live, spare dead pages, trash
+    trash = pages - 1
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (b, h, d), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (pages, kv, page, d), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (pages, kv, page, d), jnp.bfloat16)
+
+    poison = np.ones((pages, page), bool)   # per pool slot
+    bt = np.full((b, maxp), trash, np.int32)
+    dead = list(range(sum(n_live), trash))
+    nxt = 0
+    for i, (n, live) in enumerate(zip(lens, n_live)):
+        bt[i, :live] = np.arange(nxt, nxt + live)
+        for t in range(n):
+            poison[nxt + t // page, t % page] = False
+        nxt += live
+        # the rest of the row points at poisoned pages
+        bt[i, live:] = [dead[j % len(dead)] if j % 2 else trash
+                        for j in range(maxp - live)]
+    mask = jnp.asarray(poison)[:, None, :, None]
+    cl = jnp.array(lens, jnp.int32)
+    got = paged_decode_attention_pallas(
+        q, jnp.where(mask, jnp.nan, kp), jnp.where(mask, jnp.nan, vp),
+        jnp.asarray(bt), cl, interpret=True)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    want = ref.paged_decode_attention_ref(q, kp, vp, jnp.asarray(bt), cl)
+    want = np.where(np.asarray(cl)[:, None, None] == 0, 0.0,
+                    np.asarray(want, np.float32))
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
 
 
 def test_kernel_matches_model_attention():

@@ -59,6 +59,23 @@ def _spans(tracer):
     return [e for e in tracer.events if e["ph"] == "X"]
 
 
+def test_decode_wait_counts_live_pages(traced):
+    """``decode.wait`` carries the pages the decode kernel copies (each
+    row's ``ceil(ctx / page)``) beside the padded table's size."""
+    _, tracer = traced
+    spans = _spans(tracer)
+    by_sid = {e["args"]["sid"]: e for e in spans}
+    waits = [e for e in spans if e["name"] == "decode.wait"]
+    assert waits
+    longest = max(-(-(n + m) // PAGED["block_size"]) for n, m in LENS)
+    for e in waits:
+        rows = by_sid[e["args"]["parent"]]["args"]["n"]
+        live, table = e["args"]["live_pages"], e["args"]["table_pages"]
+        assert rows <= live <= min(table, rows * longest)
+    assert any(e["args"]["live_pages"] < e["args"]["table_pages"]
+               for e in waits)
+
+
 def test_clock_follows_the_executor(traced):
     _, tracer = traced
     assert tracer.host_clock

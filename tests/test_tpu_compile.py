@@ -53,10 +53,18 @@ def _spec(sharding, shape, dtype):
 
 
 # page 4 is the CLI default block size for real executors: the page is
-# the pool array's own full dim, so Mosaic's (8, 128) tiling rule holds
-@pytest.mark.parametrize("batch,page", [(1, 16), (16, 16), (16, 4)])
-def test_paged_decode_compiles(one_chip, batch, page):
-    max_pages = 2048 // page
+# the pool array's own full dim, so Mosaic's (8, 128) tiling rule holds.
+# Batch 64 is the benchmark cell's widest decode bucket: 1024 pages of 16
+# (its longest contexts, padded to a power of two); at page 4, 2048 pages,
+# since a 64 x 4096 table would not fit the chip's 1 MiB of SMEM.
+@pytest.mark.parametrize("batch,page,max_pages", [
+    pytest.param(1, 16, 128, id="1-16"),
+    pytest.param(16, 16, 128, id="16-16"),
+    pytest.param(16, 4, 512, id="16-4"),
+    pytest.param(64, 16, 1024, id="64-16-1024"),
+    pytest.param(64, 4, 2048, id="64-4-2048"),
+])
+def test_paged_decode_compiles(one_chip, batch, page, max_pages):
     pool = (POOL_PAGES, KV, page, D)
 
     def step(q, k, v, bt, cl):
