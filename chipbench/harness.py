@@ -21,6 +21,7 @@ import math
 import time
 from collections import defaultdict
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -29,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
-from chipbench import traffic, weights
+from chipbench import families, traffic
 
 BENCH_DIR = Path(__file__).resolve().parent
 REPO = BENCH_DIR.parent
@@ -54,12 +55,13 @@ class Cell:
     name: str
     chips: int
     config: dict            # the configuration file
+    family: ModuleType      # chipbench/families/<the config's "family">.py
     mix: dict               # the traffic file
     setup: dict             # the cell's own file (deployment, rate, check)
 
     @property
     def dims(self) -> dict:
-        return dims_of(self.config)
+        return self.family.dims(self.config)
 
 
 def read_bench() -> dict:
@@ -69,48 +71,19 @@ def read_bench() -> dict:
 def load_cell(name: str, bench: Optional[dict] = None,
               root: Path = REPO) -> Cell:
     """The cell ``name`` of ``bench`` (default: the repo's BENCHMARK.json),
-    with its files read from under ``root``."""
+    with its files read from under ``root``, and the architecture family
+    its configuration names (from under ``root``, else the repo's)."""
     bench = read_bench() if bench is None else bench
     (wl,) = [w for w in bench["workloads"] if w["name"] == name]
     (cf,) = [c for c in bench["configs"] if c["name"] == wl["config"]]
-    config = json.loads((root / cf["file"]).read_text())
+    path = root / cf["file"]
+    config = json.loads(path.read_text())
+    family = families.for_config(config, path, root)
     mix = json.loads((root / "chipbench" / "traffic"
                       / f"{wl['traffic']}.json").read_text())
     setup = json.loads((root / "chipbench" / "cells"
                         / f"{name}.json").read_text())
-    return Cell(name, wl["chips"], config, mix, setup)
-
-
-def dims_of(config: dict) -> dict:
-    """The sizes the benchmark computes with, from a config file written
-    with the source's own key names."""
-    return {"d_model": config["hidden_size"],
-            "n_layers": config["num_hidden_layers"],
-            "n_heads": config["num_attention_heads"],
-            "n_kv_heads": config["num_key_value_heads"],
-            "head_dim": config["head_dim"],
-            "d_ff": config["intermediate_size"],
-            "vocab_size": config["vocab_size"],
-            "rope_theta": float(config["rope_theta"]),
-            "norm_eps": float(config["rms_norm_eps"]),
-            "qk_norm": config["model_type"] == "qwen3",
-            "tie_embeddings": bool(config["tie_word_embeddings"])}
-
-
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a config file."""
-    from repro.configs.base import ModelConfig
-    d = dims_of(config)
-    if config["torch_dtype"] != "bfloat16":
-        raise ValueError("the paged path serves bfloat16 only")
-    return ModelConfig(
-        name=config["name"], arch_type="dense", n_layers=d["n_layers"],
-        d_model=d["d_model"], n_heads=d["n_heads"],
-        n_kv_heads=d["n_kv_heads"], d_ff=d["d_ff"],
-        vocab_size=d["vocab_size"], head_dim=d["head_dim"],
-        qk_norm=d["qk_norm"], rope_theta=d["rope_theta"],
-        norm_eps=d["norm_eps"], tie_embeddings=d["tie_embeddings"],
-        dtype="bfloat16")
+    return Cell(name, wl["chips"], config, family, mix, setup)
 
 
 # ---------------------------------------------------------------------------
@@ -220,8 +193,8 @@ def build_service(cell: Cell, seed: int, device):
     builds it."""
     from repro.models import build_model
     from repro.serving.api import ServeSpec
-    params = weights.make_params(seed, cell.dims, device)
-    model = build_model(model_config(cell.config))
+    params = cell.family.make_params(seed, cell.dims, device)
+    model = build_model(cell.family.model_config(cell.config))
     spec = ServeSpec(arch=cell.config["arch_id"], executor="paged",
                      **cell.mix["deployment"], **cell.setup["serve"])
     return spec.build(model=model, params=params), params

@@ -4,7 +4,7 @@ whichever bounds each chunk) over the kernel's device time in the
 profiled span."""
 import sys
 
-from chipbench import costs, tracing
+from chipbench import costs, families, tracing
 
 
 def read(run):
@@ -13,11 +13,12 @@ def read(run):
     t = tracing.kernel_seconds(run.trace["events"], tracing.PREFILL_PROGRAM)
     if t <= 0.0:
         return None
+    fam = families.of(run.dims)
     least, bounds = 0.0, set()
     for c in run.traced_calls("prefill"):
         chunk, ctx, _ = c.shape
-        s, b = costs.least_time(costs.prefill_attn_flops(run.dims, chunk, ctx),
-                                costs.prefill_attn_bytes(run.dims, chunk, ctx),
+        s, b = costs.least_time(fam.prefill_attn_flops(run.dims, chunk, ctx),
+                                fam.prefill_attn_bytes(run.dims, chunk, ctx),
                                 run.peak)
         least += s
         bounds.add(b)

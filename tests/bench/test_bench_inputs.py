@@ -7,12 +7,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import traffic, weights
+from chipbench.families import dense
 
 MIX = json.loads((Path(__file__).resolve().parents[2] / "chipbench"
                   / "traffic" / "cronus.conv.json").read_text())
-TINY = {"d_model": 16, "n_layers": 3, "n_heads": 4, "n_kv_heads": 2,
-        "head_dim": 4, "d_ff": 32, "vocab_size": 64, "qk_norm": True,
-        "tie_embeddings": False}
+TINY = {"family": "dense", "d_model": 16, "n_layers": 3, "n_heads": 4,
+        "n_kv_heads": 2, "head_dim": 4, "d_ff": 32, "vocab_size": 64,
+        "qk_norm": True, "tie_embeddings": False}
 
 
 def _plan(seed):
@@ -41,13 +42,13 @@ def test_same_seed_same_inputs():
 
 
 def test_reference_remakes_the_served_weights_bit_for_bit():
-    params = weights.make_params(2 ** 31 + 3, TINY, jax.devices()[0])
-    key = weights._key(2 ** 31 + 3)
+    params = dense.make_params(2 ** 31 + 3, TINY, jax.devices()[0])
+    key = weights.seed_key(2 ** 31 + 3)
     for layer in range(TINY["n_layers"]):
-        alone = jax.jit(lambda l: weights.layer_weights(key, l, TINY))(layer)
+        alone = jax.jit(lambda l: dense.layer_weights(key, l, TINY))(layer)
         stacked = jax.tree.map(lambda a: a[layer], params["layers"])
         assert jax.tree.all(jax.tree.map(
             lambda x, y: bool(jnp.array_equal(x, y)), alone, stacked))
-    top = jax.jit(lambda: weights.top_weights(key, TINY))()
+    top = jax.jit(lambda: dense.top_weights(key, TINY))()
     assert bool(jnp.array_equal(top["head"], params["head"]))
     assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(params))
