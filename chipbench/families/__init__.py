@@ -6,7 +6,9 @@ file is ``chipbench/families/<family>.py``. The generic code (the
 harness, the plain reference and the cost counters) reaches the
 architecture only through the functions below, so a new architecture
 comes in as new files alone: its configuration, its family file, its
-cell and its readers.
+cell and its readers. The family is also the only place that knows the
+program's executor from the inside: how to warm it and what it holds on
+the device.
 
 Each family provides:
 
@@ -46,6 +48,21 @@ Each family provides:
     over the layers, over the keys each layer's queries may see.
 ``decode_attn_flops(dims, ctx)``, ``decode_attn_bytes(dims, ctx)``
     One query over ``ctx`` keys, its own included, summed over the layers.
+``warm_phases(ex, ecfg, mix) -> {phase: (shapes, call)}``
+    For one engine's executor ``ex`` under its ``EngineConfig`` ``ecfg``,
+    the program shapes the traffic mix ``mix`` can reach in each phase
+    (``prefill``, ``decode``, ``extract_kv``, ``inject_kv``) and the call
+    that compiles one shape (``call(*shape)`` for a tuple). The calls
+    write only the executor's trash page and read one logits row back the
+    way the executor does. The harness picks the phases each engine's
+    role runs, and times and counts them.
+``device_state(ex) -> tuple``
+    The executor's device arrays besides the weights, which the harness
+    blocks on around the profiled span and deletes before the reference
+    runs.
+
+``paged.py`` holds the last two for families served by the program's
+paged executor; it is a helper, not a family.
 
 ``load`` looks first under the checkout a run was given, then here; a
 configuration without a family, or a family without a file, is an error.

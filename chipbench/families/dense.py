@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from chipbench import reference as R
 from chipbench import weights as W
+from chipbench.families import paged
 
 
 # ---------------------------------------------------------------------------
@@ -56,6 +57,14 @@ def model_config(config: dict):
         qk_norm=d["qk_norm"], rope_theta=d["rope_theta"],
         norm_eps=d["norm_eps"], tie_embeddings=d["tie_embeddings"],
         dtype="bfloat16")
+
+
+# ---------------------------------------------------------------------------
+# the program's executor: the paged one, warmed and freed as ``paged`` says
+# ---------------------------------------------------------------------------
+
+warm_phases = paged.warm_phases
+device_state = paged.device_state
 
 
 # ---------------------------------------------------------------------------

@@ -17,31 +17,19 @@ from __future__ import annotations
 import dataclasses
 import gc
 import json
-import math
 import time
 from collections import defaultdict
 from pathlib import Path
 from types import ModuleType
 from typing import Dict, List, Optional
 
-import numpy as np
-
 import jax
-import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
-from chipbench import families, traffic
+from chipbench import families
 
 BENCH_DIR = Path(__file__).resolve().parent
 REPO = BENCH_DIR.parent
-# extract_kv gathers the handoff's pages with eager ops, a handful of tiny
-# programs per block count: about 1.1 s per block count to compile cold on
-# a v5e, 0.12 s to load from the cache, and 1-10 s of stall when one first
-# compiles inside the window. Handoffs of up to this many blocks (4096
-# tokens at page 16) are warmed; warming all 512 would take the cold first
-# run past its time limit. A longer one compiles when first seen
-# (``compiles_in_window``)
-EXTRACT_WARM_BLOCKS = 256
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
@@ -200,109 +188,40 @@ def build_service(cell: Cell, seed: int, device):
     return spec.build(model=model, params=params), params
 
 
-def _pow2(n: int, lo: int) -> int:
-    b = lo
-    while b < n:
-        b *= 2
-    return b
-
-
-def _buckets(lo: int, hi: int) -> List[int]:
-    out, b = [], lo
-    while True:
-        out.append(b)
-        if b >= hi:
-            return out
-        b *= 2
-
-
-def reachable_shapes(cell: Cell, page: int, max_tokens: int,
-                     max_slots: int) -> dict:
-    """Bucket shapes the cell's length bounds can reach, as the executor
-    buckets them: prefill (chunk, pages), decode (batch, pages), handoff
-    payload blocks and inject rows."""
-    max_in, max_out = traffic.length_bounds(cell.mix)
-    prefill = set()
-    for c in range(1, min(max_tokens, max_in) + 1):
-        cb = _pow2(c, 16)
-        lo = _pow2(math.ceil(c / page), 4)
-        for pb in _buckets(lo, _pow2(math.ceil(max_in / page), 4)):
-            prefill.add((cb, pb))
-    pb_dec = _buckets(4, _pow2(math.ceil((max_in + max_out) / page), 4))
-    decode = [(bb, pb) for bb in _buckets(4, _pow2(max_slots, 4))
-              for pb in pb_dec]
-    return {"prefill": sorted(prefill), "decode": decode,
-            "extract_blocks": list(range(1, min(math.ceil(max_in / page),
-                                                EXTRACT_WARM_BLOCKS) + 1)),
-            "inject_rows": _buckets(page, _pow2(max_in, page))}
-
-
 def warm_shapes(svc, cell: Cell, log=lambda msg: None) -> dict:
-    """Run every reachable program shape once on throwaway inputs that
-    write only the trash page, exactly as the executor calls it, and read
-    one logits row back the way the executor does. Pools are left as they
-    were (the outputs are dropped). ``log`` gets one line per phase.
+    """Run every reachable program shape once, as the cell's family warms
+    it (``warm_phases``: throwaway inputs that write only the trash page,
+    one logits row read back the way the executor does), in the phases
+    each engine's role runs: prefill unless decode-only, decode unless
+    prefill-only, the first PPI's ``extract_kv`` and a pair's decoding
+    engine's ``inject_kv``. ``log`` gets one line per phase; returns the
+    shapes warmed per ``<engine>.<phase>``.
 
-    The executor's public calls need a request resident in a slot, so the
-    warm-up calls its jitted step functions (and repeats ``extract_kv``'s
-    gather) directly. If the program changes what it compiles, the window
-    compiles it: ``compiles_in_window`` shows it, and a CPU test asserts
-    that the window of a tiny cell compiles nothing."""
-    from repro.core.executor import robust_greedy
+    If the program changes what it compiles, the window compiles it:
+    ``compiles_in_window`` shows it, and a CPU test asserts that the
+    window of a tiny cell compiles nothing."""
     counts = {}
     seen_extract = False
-
-    def phase(name, items, call):
-        t = time.perf_counter()
-        for it in items:
-            call(*it) if isinstance(it, tuple) else call(it)
-        counts[name] = counts.get(name, 0) + len(items)
-        log(f"warm {name}: {len(items)} shapes in "
-            f"{time.perf_counter() - t:.1f} s")
-
     for eng in svc.engines:
-        ex, ecfg = eng.executor, eng.ecfg
-        page, trash = ex.page, ex._trash
-        shapes = reachable_shapes(cell, page, ecfg.max_batched_tokens,
-                                  ecfg.max_slots)
-
-        def prefill(cb, pb, ex=ex, page=page, trash=trash):
-            pos = np.full((1, cb), -1, np.int32)
-            pos[0, 0] = 0
-            logits, _, _ = ex._prefill_fn(
-                ex.params, ex.k_pool, ex.v_pool, np.zeros((1, cb), np.int32),
-                pos, np.full((cb,), trash * page, np.int32),
-                np.full((pb,), trash, np.int32), np.int32(1))
-            robust_greedy(logits[0, 0])
-
-        def decode(bb, pb, ex=ex, page=page, trash=trash):
-            z = np.zeros((bb,), np.int32)
-            logits, _, _ = ex._decode_fn(
-                ex.params, ex.k_pool, ex.v_pool, z, z,
-                np.full((bb,), trash * page, np.int32),
-                np.full((bb, pb), trash, np.int32), z)
-            robust_greedy(logits[0])
-
-        def extract(n, ex=ex):
-            idx = jnp.zeros((n,), jnp.int32)
-            ex.k_pool[:, idx].transpose(0, 1, 3, 2, 4).block_until_ready()
-
-        def inject(nb, ex=ex, page=page, trash=trash):
-            l_dim, _, kvh, _, hd = ex.k_pool.shape
-            rows = np.zeros((l_dim, nb, kvh, hd), jnp.bfloat16)
-            k, _ = ex._inject_fn(ex.k_pool, ex.v_pool, rows, rows,
-                                 np.full((nb,), trash * page, np.int32))
-            k.block_until_ready()
-
-        if not ecfg.decode_only:
-            phase(f"{eng.name}.prefill", shapes["prefill"], prefill)
-        if not ecfg.prefill_only:
-            phase(f"{eng.name}.decode", shapes["decode"], decode)
-        if ecfg.prefill_only and not seen_extract:
-            phase(f"{eng.name}.extract_kv", shapes["extract_blocks"], extract)
-            seen_extract = True
-        if not ecfg.prefill_only and _pair_of(svc, eng) is not None:
-            phase(f"{eng.name}.inject_kv", shapes["inject_rows"], inject)
+        ecfg = eng.ecfg
+        active = {"prefill": not ecfg.decode_only,
+                  "decode": not ecfg.prefill_only,
+                  "extract_kv": ecfg.prefill_only and not seen_extract,
+                  "inject_kv": (not ecfg.prefill_only
+                                and _pair_of(svc, eng) is not None)}
+        seen_extract = seen_extract or active["extract_kv"]
+        phases = cell.family.warm_phases(eng.executor, ecfg, cell.mix)
+        for name, on in active.items():
+            if not on:
+                continue
+            items, call = phases[name]
+            t = time.perf_counter()
+            for it in items:
+                call(*it) if isinstance(it, tuple) else call(it)
+            key = f"{eng.name}.{name}"
+            counts[key] = counts.get(key, 0) + len(items)
+            log(f"warm {key}: {len(items)} shapes in "
+                f"{time.perf_counter() - t:.1f} s")
     return counts
 
 
@@ -330,11 +249,12 @@ def make_requests(planned, prefix: str = "r"):
                     output_len=p.output_len) for p in planned]
 
 
-def wait_device(svc) -> None:
-    """Wait for every program the service has launched."""
+def wait_device(svc, family: ModuleType) -> None:
+    """Wait for every program the service has launched: block on each
+    executor's device state (``family.device_state``)."""
     for eng in svc.engines:
-        eng.executor.k_pool.block_until_ready()
-        eng.executor.v_pool.block_until_ready()
+        for a in family.device_state(eng.executor):
+            a.block_until_ready()
 
 
 def drive(svc, reqs, due_at, rec: Recorder, until: float,
@@ -376,14 +296,15 @@ def backlog(rec: Recorder, t: float) -> int:
                and not (rec.stamps.get(rid) and rec.stamps[rid][0] <= t))
 
 
-def free_service(svc, params, reqs) -> None:
-    """Free the program's device state (pools, payloads, weights) now,
-    whatever still refers to it."""
+def free_service(svc, family: ModuleType, params, reqs) -> None:
+    """Free the program's device state (each executor's, as
+    ``family.device_state`` lists it, payloads, weights) now, whatever
+    still refers to it."""
     for r in reqs:
         r.kv_payload = None
     for eng in svc.engines:
-        eng.executor.k_pool.delete()
-        eng.executor.v_pool.delete()
+        for a in family.device_state(eng.executor):
+            a.delete()
     for leaf in jax.tree.leaves(params):
         leaf.delete()
     gc.collect()

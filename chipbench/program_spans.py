@@ -1,7 +1,8 @@
 """The program's own spans, reduced to what the per-layer readers need.
 
-A run whose service was traced (``InferenceService.start_trace()`` on the
-paged executor) holds the flight recorder's host-clock events: Chrome
+A traced run (``--trace 1``) starts the service's recorder
+(``InferenceService.start_trace()``, on the paged executor) after the
+warm-up and holds the flight recorder's host-clock events: Chrome
 ``trace_event`` dicts (``repro/obs/tracer.py``, ``docs/OBSERVABILITY.md``)
 stamped with ``time.perf_counter`` in microseconds, the clock the harness
 stamps tokens with. The readers find them as ``run.program_events``; a run

@@ -9,8 +9,10 @@ The cell (configuration, traffic mix, deployment) is found by name from
 ``BENCHMARK.json``. The run makes the weights and the requests from the
 seed, builds the served path, warms every program shape the cell can
 reach, lets the cell's own traffic run for a warm span, and then measures
-for ``--seconds``. With ``--trace 1`` it profiles a few seconds inside the
-window and reports the per-layer metrics instead of the end-to-end ones.
+for ``--seconds``. With ``--trace 1`` it also records the program's own
+spans from the end of the warm-up (``svc.start_trace()``), profiles a few
+seconds inside the window, and reports the per-layer metrics instead of
+the end-to-end ones.
 Afterwards it checks the served tokens against the float32 reference.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 1 and
@@ -68,6 +70,7 @@ class RunData:
                            if t_open <= t < t_close]
         self.hbm_peak_bytes = 0
         self.trace = None     # {"events", "t0", "t1"} in a traced run
+        self.program_events = None   # the program's recorder, traced run
         self.has_pair = False
 
     @property
@@ -129,15 +132,15 @@ class Profiler:
     opens and closes on an idle device, so every program launched inside
     it ran inside it."""
 
-    def __init__(self, svc, start_at: float, seconds: float):
-        self.svc = svc
+    def __init__(self, svc, family, start_at: float, seconds: float):
+        self.svc, self.family = svc, family
         self.start_at, self.seconds = start_at, seconds
         self.dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         self.t0 = self.t1 = None
 
     def __call__(self, now: float) -> None:
         if self.t0 is None and now >= self.start_at:
-            harness.wait_device(self.svc)
+            harness.wait_device(self.svc, self.family)
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             jax.profiler.start_trace(self.dir, profiler_options=opts)
@@ -148,7 +151,7 @@ class Profiler:
 
     def stop(self) -> None:
         if self.t0 is not None and self.t1 is None:
-            harness.wait_device(self.svc)
+            harness.wait_device(self.svc, self.family)
             self.t1 = time.perf_counter()
             jax.profiler.stop_trace()
 
@@ -237,6 +240,9 @@ def run(workload: str, seed: int, seconds: float, traced: bool,
     log(f"warm-up of program shapes {warmed}: "
         f"{time.perf_counter() - t:.1f} s, {len(rec.compiles)} compiles "
         f"or cache loads so far")
+    # a traced run records the program's own spans, from after the warm-up
+    # (its compiles stay out of the record) to the close
+    tracer = svc.start_trace() if traced else None
 
     s = cell.setup
     warm_s = float(s["warm_seconds"])
@@ -248,7 +254,8 @@ def run(workload: str, seed: int, seconds: float, traced: bool,
     t_open = t_start + warm_s
     t_close = t_open + seconds
     setup_s = t_open - process_start
-    profiler = (Profiler(svc, t_open + max(0.0, (seconds - TRACE_SECONDS) / 2),
+    profiler = (Profiler(svc, cell.family,
+                         t_open + max(0.0, (seconds - TRACE_SECONDS) / 2),
                          min(TRACE_SECONDS, seconds))
                 if traced else None)
     harness.drive(svc, reqs, due_at, rec, t_close, on_time=profiler)
@@ -265,8 +272,10 @@ def run(workload: str, seed: int, seconds: float, traced: bool,
     if profiler is not None:
         events = profiler.events()
         data.trace = {"events": events, "t0": profiler.t0, "t1": profiler.t1}
-    harness.free_service(svc, params, reqs)
-    del svc, params, profiler
+    if tracer is not None:
+        data.program_events = tracer.events
+    harness.free_service(svc, cell.family, params, reqs)
+    del svc, params, profiler, tracer
 
     metrics = {}
     for m in metric_entries(bench, workload, traced):

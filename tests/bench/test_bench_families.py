@@ -1,6 +1,7 @@
 """Architecture families: the dense family against what the same code gave
-before it moved into ``chipbench/families/dense.py``, and a family that
-exists only under a run's checkout.
+before it moved into ``chipbench/families/dense.py``, a family that exists
+only under a run's checkout, and a family that brings its own warm-up and
+device state (``data/chipbench/families/stub_dense.py``).
 
 ``data/dense_golden.json`` was recorded on the CPU from the code before
 the move: a SHA-256 of every weight leaf of ``tiny-qwen3``, of the float32
@@ -123,7 +124,7 @@ def _checkout(tmp_path, config: dict, family_file=None) -> Path:
     (root / "configs").mkdir()
     (root / "configs" / "tiny-qwen3.json").write_text(json.dumps(config))
     if family_file is not None:
-        (root / "chipbench" / "families").mkdir()
+        (root / "chipbench" / "families").mkdir(exist_ok=True)
         shutil.copy(dense.__file__, root / "chipbench" / "families"
                     / family_file)
     return root
@@ -179,3 +180,68 @@ def test_a_configuration_without_a_family_is_refused(tmp_path):
     with pytest.raises(ValueError) as err:
         _run_at(root)
     assert str(root / "configs" / "tiny-qwen3.json") in str(err.value)
+
+
+# ---------------------------------------------------------------------------
+# the warm-up and the device state go through the family
+# ---------------------------------------------------------------------------
+
+# the shapes warmed per engine and phase, as the harness warmed them before
+# the warm calls moved into the family
+PARENT_WARM_COUNTS = {
+    CRONUS: {"ppi.prefill": 2, "ppi.extract_kv": 3, "cpi.prefill": 2,
+             "cpi.decode": 2, "cpi.inject_kv": 3},
+    "tiny-qwen3.tiny.worker": {"worker0.prefill": 2, "worker0.decode": 2},
+}
+PROGRAM_METRICS = {"ppi_wait_s_p90", "cpi_wait_s_p90", "handoff_ms_p90",
+                   "step_host_ms", "readback_ms_per_step",
+                   "compile_s_in_window", "migrated_overlap_share"}
+PRIVATE = ("_prefill_fn", "_decode_fn", "_inject_fn", "k_pool", "v_pool",
+           "_trash")
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_WARM_COUNTS))
+def test_warm_counts_per_phase_are_the_parents(name):
+    cell = harness.load_cell(name, _bench(), DATA)
+    svc, _ = harness.build_service(cell, SEED, jax.devices()[0])
+    assert harness.warm_shapes(svc, cell) == PARENT_WARM_COUNTS[name]
+
+
+def test_a_family_brings_its_warm_up_and_device_state():
+    """A traced run of the tiny Cronus cell under the stub family warms
+    through the stub, reads the program's metrics, and frees the stub's
+    extra device array with the pools."""
+    stub = families.load("stub_dense", DATA)
+    assert Path(stub.__file__) == (DATA / "chipbench" / "families"
+                                   / "stub_dense.py")
+    stub.WARMED.clear()
+    stub.EXTRA.clear()
+    bench = _bench()
+    bench["configs"].append({"name": "tiny-qwen3-stub", "source": "test",
+                             "file": "configs/tiny-qwen3-stub.json",
+                             "reduced": [], "why": "test"})
+    for w in bench["workloads"]:
+        if w["name"] == CRONUS:
+            w["config"] = "tiny-qwen3-stub"
+    for m in bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"] = [CRONUS]
+    res = run.run(CRONUS, SEED, 2.0, True, bench=bench, root=DATA,
+                  device=jax.devices()[0], cache=False)
+    assert res["correct"] is True
+    assert PROGRAM_METRICS <= set(res["metrics"])
+    assert res["metrics"]["compiles_in_window"]["value"] == 0
+    # one call per engine: the PPI (prefill only) and the CPI
+    assert sorted(w[:2] for w in stub.WARMED) == [(False, False),
+                                                  (True, False)]
+    assert all(w[2] == ["decode", "extract_kv", "inject_kv", "prefill"]
+               for w in stub.WARMED)
+    assert len(stub.EXTRA) == 2
+    assert all(a.is_deleted() for a in stub.EXTRA)
+
+
+@pytest.mark.parametrize("module", ["harness", "run", "reference", "costs",
+                                    "knee_sweep"])
+def test_generic_code_names_no_private_executor_attribute(module):
+    text = (harness.BENCH_DIR / f"{module}.py").read_text()
+    assert [p for p in PRIVATE if p in text] == []

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from chipbench import harness, run
+from chipbench.families import paged
 
 
 def _run():
@@ -76,9 +77,8 @@ def test_host_side_layer_readers():
 
 
 def test_reachable_shapes_follow_the_length_bounds():
-    cell = types.SimpleNamespace(mix={"input_len": {"max": 40},
-                                      "output_len": {"max": 10}})
-    s = harness.reachable_shapes(cell, page=16, max_tokens=32, max_slots=5)
+    mix = {"input_len": {"max": 40}, "output_len": {"max": 10}}
+    s = paged.reachable_shapes(mix, page=16, max_tokens=32, max_slots=5)
     # chunks of 1-16 and 17-32 tokens, contexts up to 40 tokens (3 pages)
     assert s["prefill"] == [(16, 4), (32, 4)]
     assert s["decode"] == [(4, 4), (8, 4)]

@@ -4,11 +4,9 @@ cells.
 
 ``tests/bench/data/program_trace.json`` holds the flight recorder's
 host-clock events of the tiny Cronus cell (five requests run to the end).
-``tests/bench/data/program_metrics.json`` holds the metrics' entries as
-``BENCHMARK.json`` would list them. A traced run reads them once the
-service's recorder is started before the first request and its events
-reach the readers as ``run.program_events``; the tests below do both
-from the test.
+A traced run starts the service's recorder after the warm-up and hands
+its events to the readers as ``run.program_events``; an untraced run
+starts none.
 """
 import importlib.util
 import json
@@ -26,8 +24,10 @@ REPO = DATA.parents[2]
 CRONUS, WORKER = "tiny-qwen3.tiny.cronus", "tiny-qwen3.tiny.worker"
 SEED = 2 ** 31 + 29
 FIXTURE = json.loads((DATA / "program_trace.json").read_text())
-ENTRIES = json.loads((DATA / "program_metrics.json").read_text())["per_layer"]
-NAMES = [m["name"] for m in ENTRIES]
+NAMES = ["ppi_wait_s_p90", "cpi_wait_s_p90", "handoff_ms_p90",
+         "step_host_ms", "readback_ms_per_step", "compile_s_in_window",
+         "migrated_overlap_share"]
+ENTRIES = [m for m in harness.read_bench()["per_layer"] if m["name"] in NAMES]
 PAIR_ONLY = {m["name"] for m in ENTRIES if "workloads" in m}
 
 _tr = importlib.util.spec_from_file_location(
@@ -151,48 +151,46 @@ def test_readers_read_nothing_without_the_program_spans():
 
 
 # ---------------------------------------------------------------------------
-# traced runs of the tiny cells, the recorder started before the first
-# request
+# runs of the tiny cells, with the recorder that a traced run starts
 # ---------------------------------------------------------------------------
 
 def _bench():
-    """The repo's BENCHMARK.json with the tiny cells and the seven
-    entries; a metric kept to the Cronus cell is kept to the tiny one."""
+    """The repo's BENCHMARK.json with the tiny cells; a metric kept to the
+    Cronus cell is kept to the tiny one."""
     bench = harness.read_bench()
     bench.update(json.loads((DATA / "workloads.json").read_text()))
-    extra = [dict(m) for m in ENTRIES]
-    for m in bench["per_layer"] + extra:
+    for m in bench["per_layer"]:
         if "workloads" in m:
             m["workloads"] = [CRONUS]
-    bench["per_layer"] = bench["per_layer"] + extra
     return bench
 
 
-@pytest.fixture
-def recorder(monkeypatch):
-    """Start the service's recorder before the first request and hand its
-    events to the readers; returns the tracers started."""
-    tracers = []
-    drive = harness.drive
+def _run_seen(monkeypatch, name, traced):
+    """One run of a tiny cell, and the record its readers were given."""
+    seen = []
+    read = run.read_metric
 
-    def traced_drive(svc, *a, **k):
-        tracers.append(svc.start_trace())
-        return drive(svc, *a, **k)
+    def spy(metric, data):
+        seen.append(data)
+        return read(metric, data)
+    monkeypatch.setattr(run, "read_metric", spy)
+    res = run.run(name, SEED, 2.0, traced, bench=_bench(), root=DATA,
+                  device=jax.devices()[0], cache=False)
+    assert seen and all(d is seen[0] for d in seen)
+    return res, seen[0]
 
-    class Data(run.RunData):
-        def __init__(self, *a, **k):
-            super().__init__(*a, **k)
-            self.program_events = tracers[-1].events
 
-    monkeypatch.setattr(harness, "drive", traced_drive)
-    monkeypatch.setattr(run, "RunData", Data)
-    return tracers
+def test_entries_on_the_result_line():
+    assert [m["name"] for m in ENTRIES] == NAMES
+    assert all(m["workloads"] == ["qwen2-7b-l20.cronus.conv-over"]
+               for m in ENTRIES if m["name"] in PAIR_ONLY)
+    assert PAIR_ONLY == {"ppi_wait_s_p90", "cpi_wait_s_p90",
+                         "handoff_ms_p90", "migrated_overlap_share"}
 
 
 @pytest.mark.parametrize("name", [CRONUS, WORKER])
-def test_traced_tiny_cell_reports_the_program_metrics(recorder, name):
-    res = run.run(name, SEED, 2.0, True, bench=_bench(), root=DATA,
-                  device=jax.devices()[0], cache=False)
+def test_traced_tiny_cell_reports_the_program_metrics(monkeypatch, name):
+    res, data = _run_seen(monkeypatch, name, True)
     got = res["metrics"]
     want = set(NAMES) if name == CRONUS else set(NAMES) - PAIR_ONLY
     assert want <= set(got)
@@ -202,16 +200,25 @@ def test_traced_tiny_cell_reports_the_program_metrics(recorder, name):
     # the warm-up leaves nothing to compile in the window
     assert got["compile_s_in_window"]["value"] == 0
     assert res["correct"] is True
-    (tracer,) = recorder
-    assert tracer.host_clock
+    # on the host clock, from the open loop's start to the close
+    prog = program_spans.Program(data.program_events)
+    t_start = data.t_open - data.cell.setup["warm_seconds"]
+    assert prog.spans
+    assert t_start <= min(s.t0 for s in prog.spans)
+    assert max(s.t0 for s in prog.spans) < data.t_close
     if name == CRONUS:
-        prog = program_spans.Program(tracer.events)
         split = [r for r in prog.submit if prog.ttft_parts(r) is not None]
         assert split
         for rid in split:
             p = prog.ttft_parts(rid)
             assert sum(v for k, v in p.items() if k != "ttft") \
                 <= p["ttft"] + 1e-3
+
+
+def test_untraced_run_starts_no_recorder(monkeypatch):
+    res, data = _run_seen(monkeypatch, CRONUS, False)
+    assert data.program_events is None
+    assert not set(NAMES) & set(res["metrics"])
 
 
 def test_trace_report_checks_a_host_clock_trace_of_the_tiny_cell(tmp_path):
